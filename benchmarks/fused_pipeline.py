@@ -83,7 +83,7 @@ def analytic_overlap(dev, pages: int, n_p: int, q: int) -> dict:
 
 
 def kernel_sweep():
-    dev = tpu_device()
+    dev = tpu_device(os.environ.get("REPRO_TPU_DEVICE"))
     rng = np.random.default_rng(0)
     rows = []
     for n_p in PAGE_NP:

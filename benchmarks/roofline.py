@@ -4,9 +4,10 @@ section giving the SAME compute/memory terms to the search hot-path kernels
 (page_scan / pq_adc / fused_page_rank) so the fused pipeline's position on
 the roofline sits next to the model kernels'.
 
-Terms (per device, seconds per step), priced on the named device table
-shared with the analytic model (repro.core.device_model.TPU_DEVICES;
-REPRO_TPU_DEVICE selects, default v5e):
+Terms (per device, seconds per step), priced on the device table shared
+with the analytic model (repro.core.device_model.TPU_DEVICES): the entry of
+the device_kind REPRO_TPU_DEVICE names (e.g. "TPU v5 lite"), else the
+attached TPU's; there is no default chip:
   compute    = HLO_FLOPs / peak_FLOPs            (v5e: 197 TFLOP/s bf16)
   memory     = HLO_bytes / HBM_bw                (v5e: 819 GB/s)
   collective = collective_bytes / link_bw        (v5e: ~50 GB/s/link ICI)
@@ -24,17 +25,11 @@ term; ratio MODEL_FLOPS/HLO_FLOPs exposes remat/redundant compute.
 from __future__ import annotations
 
 import json
+import os
 import sys
 from pathlib import Path
 
 from repro.core.device_model import tpu_device
-
-# module-level names kept for importers; values now come from the shared
-# device table (REPRO_TPU_DEVICE selects the entry, default v5e)
-_DEV = tpu_device()
-PEAK_FLOPS = _DEV.peak_flops
-HBM_BW = _DEV.hbm_bw
-LINK_BW = _DEV.link_bw
 
 ART = Path(__file__).resolve().parent / "artifacts"
 
@@ -102,6 +97,7 @@ def load(mesh_tag: str, tag: str = ""):
 
 def analyze(mesh_tag="single", tag=""):
     from repro.configs import get_config, get_shape
+    dev = tpu_device(os.environ.get("REPRO_TPU_DEVICE"))
     out = []
     for rec in load(mesh_tag, tag):
         if not rec.get("ok"):
@@ -113,12 +109,12 @@ def analyze(mesh_tag="single", tag=""):
         n_dev = rec["n_devices"]
         coll_bytes = sum(v for k, v in rec["collectives"].items()
                         if not k.endswith("_count"))
-        t_comp = rec["flops"] / PEAK_FLOPS
-        t_mem = rec["traffic_bytes"] / HBM_BW
-        t_coll = coll_bytes / LINK_BW
+        t_comp = rec["flops"] / dev.peak_flops
+        t_mem = rec["traffic_bytes"] / dev.hbm_bw
+        t_coll = coll_bytes / dev.link_bw
         mf = model_flops(cfg, shape, n_dev)
         mb = model_bytes(cfg, shape, n_dev, rec)
-        t_mem_model = mb / HBM_BW
+        t_mem_model = mb / dev.hbm_bw
         # dominant term: compute (HLO, trip-corrected), memory (analytic
         # model; CPU-HLO traffic reported alongside as an upper bound),
         # collective (HLO, exact SPMD sizes)
@@ -130,7 +126,7 @@ def analyze(mesh_tag="single", tag=""):
         # (its compute at peak FLOPs, or its minimal traffic at peak BW)
         # over the modeled step bound — 1.0 = step runs as fast as its
         # useful work possibly allows
-        useful = max(mf / PEAK_FLOPS, t_mem_model)
+        useful = max(mf / dev.peak_flops, t_mem_model)
         out.append({
             "arch": rec["arch"], "shape": rec["shape"], "mesh": mesh_tag,
             "compute_s": f"{t_comp:.4f}",
@@ -155,6 +151,7 @@ def disk_kernels(n_pages: int = 8, n_p: int = 8, d: int = 128, m: int = 16,
     two halves' work under ONE memory pass and one dispatch; its bound is
     max(compute, memory) instead of their sum, which is exactly the overlap
     the measured benchmark (benchmarks/fused_pipeline.py) checks."""
+    dev = tpu_device(os.environ.get("REPRO_TPU_DEVICE"))
     recs = n_pages * n_p
     vec_bytes = recs * d * 4
     code_bytes = recs * m
@@ -168,12 +165,12 @@ def disk_kernels(n_pages: int = 8, n_p: int = 8, d: int = 128, m: int = 16,
             ("pq_adc", adc_flops, code_bytes + lut_bytes + out_bytes),
             ("fused_page_rank", scan_flops + adc_flops,
              vec_bytes + code_bytes + q * d * 4 + lut_bytes + 2 * out_bytes)):
-        t_c = _DEV.compute_s(flops)
-        t_m = _DEV.memory_s(bytes_)
+        t_c = dev.compute_s(flops)
+        t_m = dev.memory_s(bytes_)
         fused = name == "fused_page_rank"
         bound = max(t_c, t_m) if fused else t_c + t_m
         rows.append({
-            "kernel": name, "device": _DEV.name,
+            "kernel": name, "device": dev.name,
             "pages": n_pages, "n_p": n_p, "d": d, "M": m, "Q": q,
             "flops": f"{flops:.3e}", "bytes": f"{bytes_:.3e}",
             "intensity_flop_per_byte": f"{flops / bytes_:.1f}",
@@ -199,7 +196,7 @@ def main(argv=None):
             print(",".join(str(r.get(c, "")) for c in cols))
     rows = disk_kernels()
     cols = list(rows[0])
-    print(f"== roofline (disk-path kernels, {_DEV.name}) ==")
+    print(f"== roofline (disk-path kernels, {rows[0]['device']}) ==")
     print(",".join(cols))
     for r in rows:
         print(",".join(str(r.get(c, "")) for c in cols))

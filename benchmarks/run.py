@@ -2,7 +2,10 @@
 
 Default is the QUICK grid (2 datasets x 3 Ls — CPU-feasible end-to-end);
 set REPRO_BENCH_FULL=1 for all four datasets and the full L sweeps.
-Prints `name,us_per_call,derived`-style CSV sections per module.
+Prints `name,us_per_call,derived`-style CSV sections per module. The
+kernels and roofline sections price the attached TPU, or off a TPU the
+device_kind REPRO_TPU_DEVICE names (e.g. "TPU v5 lite"); without either they
+fail rather than assume a chip.
 """
 from __future__ import annotations
 

@@ -28,7 +28,7 @@ bytes at 819 GB/s with DMA/compute overlap — see benchmarks/roofline.py.
 from __future__ import annotations
 
 import dataclasses
-import os
+from typing import Optional
 
 import numpy as np
 
@@ -53,22 +53,35 @@ class TPUDevice:
         return nbytes / self.hbm_bw
 
 
+# keyed by `jax.Device.device_kind`, the string the runtime reports for the
+# attached chip; v5e peaks from Google Cloud's "TPU v5e" documentation
 TPU_DEVICES = {
-    "v5e": TPUDevice("v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9),
-    "v4": TPUDevice("v4", peak_flops=275e12, hbm_bw=1228e9, link_bw=100e9,
-                    vmem_bytes=32 * 2**20),
-    "v5p": TPUDevice("v5p", peak_flops=459e12, hbm_bw=2765e9, link_bw=100e9),
+    "TPU v5 lite": TPUDevice("v5e", peak_flops=197e12, hbm_bw=819e9,
+                             link_bw=50e9),
+    "TPU v4": TPUDevice("v4", peak_flops=275e12, hbm_bw=1228e9,
+                        link_bw=100e9, vmem_bytes=32 * 2**20),
+    "TPU v5p": TPUDevice("v5p", peak_flops=459e12, hbm_bw=2765e9,
+                         link_bw=100e9),
 }
 
 
-def tpu_device(name: str = "") -> TPUDevice:
-    """Resolve a device table entry; `REPRO_TPU_DEVICE` overrides the
-    default (v5e — the generation the paper-era kernels were sized for)."""
-    name = name or os.environ.get("REPRO_TPU_DEVICE", "v5e")
-    if name not in TPU_DEVICES:
-        raise ValueError(f"unknown TPU device {name!r}; "
-                         f"choose from {sorted(TPU_DEVICES)}")
-    return TPU_DEVICES[name]
+def tpu_device(kind: Optional[str] = None) -> TPUDevice:
+    """The peak table entry for `kind` (a `device_kind` string), or for the
+    attached chip when `kind` is None. There is no default generation: with
+    no TPU attached the caller names the chip it prices, and a kind missing
+    from the table is an error."""
+    if kind is None:
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            raise RuntimeError(
+                f"no TPU attached (JAX platform {dev.platform!r}): pass the "
+                f"device_kind to price, one of {sorted(TPU_DEVICES)}")
+        kind = dev.device_kind
+    if kind not in TPU_DEVICES:
+        raise ValueError(f"unknown TPU device_kind {kind!r}; "
+                         f"the table holds {sorted(TPU_DEVICES)}")
+    return TPU_DEVICES[kind]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -5,8 +5,8 @@ like DiskANN's page-aligned format (Fig. 1). All-in-Storage (AiSAQ, §4.2.2)
 additionally co-locates the PQ codes of the record's neighbors, which shrinks
 n_p and grows the on-disk footprint — modeled by `record_bytes`.
 
-On TPU (see DESIGN.md §2) a page is an HBM tile of shape (n_p, d) fetched to
-VMEM by the page_scan Pallas kernel; n_p is padded to a sublane multiple.
+On TPU a page is an HBM tile of shape (n_p, d) that the page_scan Pallas
+kernel (kernels/page_scan.py) fetches to VMEM as one block.
 """
 from __future__ import annotations
 

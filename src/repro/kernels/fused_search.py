@@ -23,8 +23,12 @@ schedule as a single PrefetchScalarGridSpec grid:
 VMEM budget per step (f32): page tile n_p*d*4 + code tile n_p*M + query
 block d*Q*4 + stacked LUT M*256*Q*4 (the per-query LUTs live transposed as
 (M, 256, Q) so each subspace's scan is one MXU matmul for the whole query
-block) + two output tiles n_p*Q*4 — at the default shape (n_p=8, d=128,
-M=16, Q=256) that is ~4.3 MiB, double-buffered well inside 16 MiB.
+block) + two output tiles n_p*Q*4, plus the (n_p, M*256) f32 one-hot — at
+the sift-like page shape (n_p=10, d=128, M=16, Q=256) that is ~4.3 MiB,
+~8.5 MiB double-buffered, inside the v5e's 16 MiB default scoped VMEM
+(tests/test_tpu_compile.py compiles it for a described v5e at that shape).
+The uint8 codes are widened to int32 before the one-hot broadcast: Mosaic
+has no layout for the (n_p, M) -> (n_p, M, 1) shape cast on 8-bit vectors.
 
 The kernel is a MEASUREMENT surface, not a result path: `pipeline="fused"`
 searches still take their results from the reference beam search (bit
@@ -51,7 +55,8 @@ def _fused_kernel(page_ids_ref, q_ref, qsq_ref, lut_ref, pages_ref,
     x = pages_ref[0].astype(jnp.float32)                    # (n_p, d)
     q = q_ref[...].astype(jnp.float32)                      # (d, Q)
     x2 = jnp.sum(jnp.square(x), axis=-1, keepdims=True)     # (n_p, 1)
-    xq = jnp.dot(x, q, preferred_element_type=jnp.float32)  # MXU (n_p, Q)
+    xq = jnp.dot(x, q, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)      # MXU (n_p, Q)
     out_exact_ref[0] = x2 - 2.0 * xq + qsq_ref[...]
 
     # Fusion keeps the WHOLE stacked LUT resident as one VMEM block, so the
@@ -61,19 +66,20 @@ def _fused_kernel(page_ids_ref, q_ref, qsq_ref, lut_ref, pages_ref,
     # the matmul's own reduction. (The standalone page_adc/pq_adc path keeps
     # the per-subspace form; this bigger matmul is what the fused schedule
     # buys on top of the double buffer.)
-    codes = codes_ref[0]                                    # (n_p, M) uint8
+    codes = codes_ref[0].astype(jnp.int32)                  # (n_p, M)
     n_p, m = codes.shape
     qn = q_ref.shape[1]
-    onehot = (codes[:, :, None].astype(jnp.int32)
+    onehot = (codes[:, :, None]
               == jax.lax.broadcasted_iota(jnp.int32, (n_p, m, 256), 2))
     out_adc_ref[0] = jnp.dot(
         onehot.astype(jnp.float32).reshape(n_p, m * 256),
         lut_ref[...].reshape(m * 256, qn),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_page_rank(pages, page_codes, page_ids, q, lut, *, interpret=True):
+def fused_page_rank(pages, page_codes, page_ids, q, lut, *, interpret):
     """One pipelined grid over the page schedule.
 
     pages (P, n_p, d); page_codes (P, n_p, M) uint8; page_ids (W,) int32
@@ -124,12 +130,13 @@ def _adc_kernel(page_ids_ref, lut_ref, codes_ref, out_ref):
         onehot = (codes[:, j][:, None].astype(jnp.int32)
                   == jax.lax.broadcasted_iota(jnp.int32, (n_p, 256), 1))
         acc = acc + jnp.dot(onehot.astype(jnp.float32), lut_ref[j],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     out_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def page_adc(page_codes, page_ids, lut, *, interpret=True):
+def page_adc(page_codes, page_ids, lut, *, interpret):
     """The ADC half alone, its own grid and dispatch — the second of the
     two calls the fused kernel replaces (the exact half alone is
     kernels/page_scan.py). page_codes (P, n_p, M) uint8; page_ids (W,);

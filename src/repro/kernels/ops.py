@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs as Python/jnp per grid step); on a real TPU set interpret=False (the
-default flips automatically on TPU backends).
+The wrappers pick the execution mode from the backend: Mosaic-compiled on a
+TPU, interpret mode (the kernel body runs as jnp per grid step) on the CPU,
+and an error on any other backend — these are TPU kernels, and no backend
+silently stands in for the chip.
 
 Shape bucketing: the raw kernels are jitted per exact shape, so a beam
 width that moves every step (DynamicWidth shrinking/growing the frontier,
@@ -27,8 +28,15 @@ from repro.kernels.pq_adc import pq_adc as _pq_adc
 _MIN_BUCKET = 4     # smallest width bucket (floor of the power-of-two ladder)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for a TPU or interpret on the CPU; "
+        f"JAX's backend is {backend!r}")
 
 
 def bucket_size(n: int, floor: int = _MIN_BUCKET) -> int:
@@ -58,7 +66,7 @@ def page_scan(pages, page_ids, q):
     w = page_ids.shape[0]
     b = bucket_size(w)
     out = _page_scan(pages, _pad_ids(page_ids, b), q,
-                     interpret=not _on_tpu())
+                     interpret=_interpret())
     return out[:w]
 
 
@@ -71,7 +79,7 @@ def pq_adc(codes, lut, block_n=512):
     b = bucket_size(n, floor=min(block_n, bucket_size(n)))
     if b > n:
         codes = jnp.pad(codes, ((0, b - n), (0, 0)))
-    out = _pq_adc(codes, lut, block_n=block_n, interpret=not _on_tpu(),
+    out = _pq_adc(codes, lut, block_n=block_n, interpret=_interpret(),
                   nvalid=jnp.int32(n))
     return out[:n]
 
@@ -83,7 +91,7 @@ def fused_page_rank(pages, page_codes, page_ids, q, lut):
     w = page_ids.shape[0]
     b = bucket_size(w)
     exact, adc = _fused_page_rank(pages, page_codes, _pad_ids(page_ids, b),
-                                  q, lut, interpret=not _on_tpu())
+                                  q, lut, interpret=_interpret())
     return exact[:w], adc[:w]
 
 
@@ -93,5 +101,5 @@ def page_adc(page_codes, page_ids, lut):
     w = page_ids.shape[0]
     b = bucket_size(w)
     out = _page_adc(page_codes, _pad_ids(page_ids, b), lut,
-                    interpret=not _on_tpu())
+                    interpret=_interpret())
     return out[:w]

@@ -1,4 +1,4 @@
-"""page_scan — the paper's disk path, TPU-native (DESIGN.md §2).
+"""page_scan — the paper's disk path, TPU-native.
 
 One kernel fuses three of the paper's techniques:
   * the "4 KB random page read" becomes a dynamic-index HBM->VMEM block DMA
@@ -11,9 +11,10 @@ One kernel fuses three of the paper's techniques:
     matmul — computing only the target record would waste the tile anyway.
 
 Layout contract (TPU tiling): d padded to 128 lanes, n_p to 8 sublanes,
-Q (query block) a multiple of 128 for MXU efficiency. The CPU container runs
-the kernel in interpret mode; tests/test_kernels.py sweeps shapes/dtypes
-against ref.page_scan_ref.
+Q (query block) a multiple of 128 for MXU efficiency. `interpret` is
+required: True runs the body as jnp on the CPU (tests/test_kernels.py sweeps
+shapes/dtypes against ref.page_scan_ref), False compiles it with Mosaic for
+the TPU (tests/test_tpu_compile.py compiles it for a described v5e).
 """
 from __future__ import annotations
 
@@ -31,12 +32,14 @@ def _kernel(page_ids_ref, q_ref, qsq_ref, pages_ref, out_ref):
     x = pages_ref[0].astype(jnp.float32)                  # (n_p, d)
     q = q_ref[...].astype(jnp.float32)                    # (d, Q)
     x2 = jnp.sum(jnp.square(x), axis=-1, keepdims=True)   # (n_p, 1)
-    xq = jnp.dot(x, q, preferred_element_type=jnp.float32)  # MXU (n_p, Q)
+    # f32 passes: at default precision the MXU rounds float pages to bf16
+    xq = jnp.dot(x, q, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)      # MXU (n_p, Q)
     out_ref[0] = x2 - 2.0 * xq + qsq_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def page_scan(pages, page_ids, q, *, interpret=True):
+def page_scan(pages, page_ids, q, *, interpret):
     """pages (P, n_p, d); page_ids (W,); q (Q, d) -> (W, n_p, Q) f32."""
     p, n_p, d = pages.shape
     w = page_ids.shape[0]

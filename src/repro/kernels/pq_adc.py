@@ -3,11 +3,17 @@
 The paper's PQ filter (§4.1.1) scans memory-resident codes against a per-query
 lookup table. A CPU implementation gathers lut[m, code]; gathers are the weak
 operation on TPU's vector unit, so the TPU-native form turns each subspace
-scan into a one-hot (bn, 256) x (256,) matmul on the MXU — gather-free and
-sublane-aligned. The LUT (M, 256) f32 = 16 KiB lives wholly in VMEM; codes
+scan into a (1, 256) x (256, bn) matmul against a one-hot on the MXU —
+gather-free. The LUT (M, 256) f32 = 16 KiB lives wholly in VMEM; codes
 stream from HBM block-by-block through the grid pipeline (double-buffered).
 
-Tiling contract: block_n multiple of 8 (sublanes); 256 = 2 lanes of 128.
+Tiling contract: the scores leave as one lane-dense (1, block_n) row of a
+(1, N) output (a 1-D f32 output block does not match XLA's T(1024) tiling of
+a 1-D array on the TPU), so a compiled block_n is a multiple of 128; 256 = 2
+lanes of 128. `interpret` is required (True: jnp on the CPU; False: Mosaic).
+The matmuls run at HIGHEST precision: at the default one the MXU rounds the
+f32 LUT to bf16, which moved v5e scores by up to 0.05 against a float64
+reference; the one-hot operand is exact either way.
 
 Pad guard: N is padded up to a block_n multiple, and the padded tail used to
 score the zero pad's codes as if they were real records — garbage distances
@@ -28,25 +34,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(nvalid_ref, codes_ref, lut_ref, out_ref):
-    codes = codes_ref[...]                                # (bn, M) uint8
+    codes = codes_ref[...].astype(jnp.int32)              # (bn, M)
     lut = lut_ref[...]                                    # (M, 256) f32
     bn, m = codes.shape
-    acc = jnp.zeros((bn,), jnp.float32)
+    acc = jnp.zeros((1, bn), jnp.float32)
     for j in range(m):  # M is small and static: unrolled, each an MXU matmul
-        onehot = (codes[:, j][:, None].astype(jnp.int32)
+        onehot = (codes[:, j][:, None]
                   == jax.lax.broadcasted_iota(jnp.int32, (bn, 256), 1))
-        acc = acc + jnp.dot(onehot.astype(jnp.float32), lut[j],
-                            preferred_element_type=jnp.float32)
+        # (1, 256) x (bn, 256)^T -> (1, bn): the result is one lane-dense
+        # row, so the output block is (1, bn) of a (1, N) array
+        acc = acc + jax.lax.dot_general(
+            lut[j:j + 1], onehot.astype(jnp.float32),
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
     # pad-tail guard: rows past the true length scored the zero pad's codes
     # — poison them so no caller can rank the pad as a candidate
     row = pl.program_id(0) * bn + jax.lax.broadcasted_iota(
-        jnp.int32, (bn,), 0)
+        jnp.int32, (1, bn), 1)
     out_ref[...] = jnp.where(row < nvalid_ref[0], acc, jnp.inf)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret", "keep_pad"))
-def pq_adc(codes, lut, *, block_n=512, interpret=True, keep_pad=False,
+def pq_adc(codes, lut, *, interpret, block_n=512, keep_pad=False,
            nvalid=None):
     """codes (N, M) uint8; lut (M, 256) f32 -> (N,) f32.
 
@@ -68,12 +78,12 @@ def pq_adc(codes, lut, *, block_n=512, interpret=True, keep_pad=False,
             pl.BlockSpec((block_n, m), lambda i, nv: (i, 0)),
             pl.BlockSpec((m, 256), lambda i, nv: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i, nv: (i,)),
+        out_specs=pl.BlockSpec((1, block_n), lambda i, nv: (0, i)),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         interpret=interpret,
-    )(nv, codes, lut)
+    )(nv, codes, lut)[0]
     return out if keep_pad else out[:n]

@@ -78,11 +78,6 @@ def gpipe(stage_fn, stage_params, x, n_micro: int, *, axis: str, mesh):
         return out.reshape(b, *x_rep.shape[1:])
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    if hasattr(jax, "shard_map"):           # jax >= 0.6 top-level API
-        smap = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_vma=False)
-    else:                                   # 0.4.x experimental spelling
-        from jax.experimental.shard_map import shard_map
-        smap = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                         check_rep=False)
+    smap = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)
     return smap(stage_params, x)

@@ -79,6 +79,20 @@ else:
         pass
 
 
+def test_tpu_device_table_is_keyed_by_device_kind():
+    from repro.core.device_model import tpu_device
+    assert tpu_device("TPU v5 lite").name == "v5e"
+    with pytest.raises(ValueError, match="'v5e'"):   # a name, not a kind
+        tpu_device("v5e")
+
+
+def test_tpu_device_assumes_no_chip():
+    """With no TPU attached and no kind named there is nothing to price."""
+    from repro.core.device_model import tpu_device
+    with pytest.raises(RuntimeError, match="'cpu'"):
+        tpu_device()
+
+
 def test_error_feedback_unbiased():
     from repro.training.compression import ef_compress_tree, init_error_state
     rng = np.random.default_rng(0)

@@ -14,6 +14,7 @@ Four claims pinned here:
      pipeline=True — the fused kernel is a measurement surface, never a
      result path — and carries measured_step_us next to the modeled time.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -128,6 +129,22 @@ def test_pq_adc_bucketed_wrapper():
 
 
 # -- 3. bucketing bounds compiles -------------------------------------------
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False)])
+def test_wrappers_pick_mode_from_backend(monkeypatch, backend, interpret):
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert kops._interpret() is interpret
+
+
+def test_wrappers_refuse_other_backends(monkeypatch):
+    """No backend silently stands in for the chip."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        kops._interpret()
 
 
 def test_bucket_size_ladder():
