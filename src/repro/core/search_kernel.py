@@ -19,6 +19,10 @@ TEMPORAL order, which is what the stateful cache subsystem
 (repro/io/page_cache.py: LRU/FIFO/2Q replay, look-ahead prefetch) consumes.
 Both trackers are static flags, so untracked carries compile out entirely.
 
+The kernel's stages run under `jax.named_scope` — `pq_lut`, `pq_lookup`,
+`select`, `page_gather`, `exact_dist`, `merge`, `rerank` — so each device
+op's `op_name` metadata names its stage; scopes change no arithmetic.
+
 Technique mapping (SearchConfig):
   PQ            — always on (the paper's §6 baseline): neighbors ranked by
                   memory-resident ADC distances; exact distances only for
@@ -52,6 +56,7 @@ import numpy as np
 from repro.core.searchutils import (INF, SENTINEL, dedup_merge_topL, sq_dists,
                                     top_w_unexpanded)
 from repro.core.stats import QueryStats
+from repro.obs import span
 
 
 @functools.partial(
@@ -72,29 +77,34 @@ def _search_batch(page_vids, page_vecs, page_nbrs, vid2page, vid2slot,
     w_cap = min(width + (spec if pipeline else 0), L)
 
     def one(qv, ent, ent_ok):
-        lut = jnp.sum(jnp.square(pq_centroids
-                                 - qv.reshape(m, 1, dsub)), axis=-1)  # (M,256)
+        with jax.named_scope("pq_lut"):
+            lut = jnp.sum(jnp.square(pq_centroids
+                                     - qv.reshape(m, 1, dsub)),
+                          axis=-1)                      # (M,256)
 
         def pq_dist(ids):
-            safe = jnp.minimum(jnp.maximum(ids, 0), n - 1)
-            codes = pq_codes[safe]                      # (.., M)
-            d = jnp.take_along_axis(
-                lut.T, codes.astype(jnp.int32), axis=0)  # broadcast gather
-            # lut.T is (256, M); gather rows by code per column
-            return jnp.sum(d, axis=-1)
+            with jax.named_scope("pq_lookup"):
+                safe = jnp.minimum(jnp.maximum(ids, 0), n - 1)
+                codes = pq_codes[safe]                  # (.., M)
+                d = jnp.take_along_axis(
+                    lut.T, codes.astype(jnp.int32), axis=0)  # broadcast gather
+                # lut.T is (256, M); gather rows by code per column
+                return jnp.sum(d, axis=-1)
 
         # candidate list: keys = [rank_key, exact_dist]; flags = [expanded,
         # exact_known]
         cap = L + w_cap * (n_p if page_search else 0) + w_cap * page_nbrs.shape[2]
         e_pq = pq_dist(ent)
-        ids0 = jnp.where(ent_ok, ent, SENTINEL)
-        pad = cap - ids0.shape[0]
-        ids = jnp.concatenate([ids0, jnp.full((pad,), SENTINEL, jnp.int32)])
-        keys = jnp.stack([jnp.where(ent_ok, e_pq, INF),
-                          jnp.full(ids0.shape, INF)], 1)
-        keys = jnp.concatenate([keys, jnp.full((pad, 2), INF)], 0)
-        flags = jnp.zeros((cap, 2), bool)
-        ids, keys, flags = dedup_merge_topL(ids, keys, flags, L)
+        with jax.named_scope("merge"):
+            ids0 = jnp.where(ent_ok, ent, SENTINEL)
+            pad = cap - ids0.shape[0]
+            ids = jnp.concatenate([ids0,
+                                   jnp.full((pad,), SENTINEL, jnp.int32)])
+            keys = jnp.stack([jnp.where(ent_ok, e_pq, INF),
+                              jnp.full(ids0.shape, INF)], 1)
+            keys = jnp.concatenate([keys, jnp.full((pad, 2), INF)], 0)
+            flags = jnp.zeros((cap, 2), bool)
+            ids, keys, flags = dedup_merge_topL(ids, keys, flags, L)
 
         zero = jnp.zeros((), jnp.float32)
         # visited[p] = page p was charged to the device at least once; slot
@@ -114,106 +124,123 @@ def _search_batch(page_vids, page_vecs, page_nbrs, vid2page, vid2slot,
 
         def cond(st):
             ids, keys, flags, it = st[0], st[1], st[2], st[3]
-            open_ = jnp.any((ids < SENTINEL) & ~flags[:, 0]
-                            & (keys[:, 0] < INF))
-            return open_ & (it < max_iters)
+            with jax.named_scope("select"):
+                open_ = jnp.any((ids < SENTINEL) & ~flags[:, 0]
+                                & (keys[:, 0] < INF))
+                return open_ & (it < max_iters)
 
         def body(st):
             (ids, keys, flags, it, w_dyn, stall, visited, trace,
              pages_m, cache_m, nread_m, neff_m, full_m, pq_m_) = st
             best_before = keys[0, 0]
 
-            w_now = (jnp.minimum(jnp.float32(dw_max), w_dyn)
-                     if dynamic_width else jnp.float32(width))
-            w_sel = jnp.minimum(w_now, jnp.float32(width)).astype(jnp.int32)
-            fidx, active = top_w_unexpanded(
-                keys[:, 0], flags[:, 0], ids < SENTINEL, w_cap,
-                w_dynamic=(w_sel + (spec if pipeline else 0)))
-            # pipeline: the first w_sel are confirmed, the rest speculative
-            fids = jnp.where(active, ids[fidx], SENTINEL)
-            neff_m = neff_m + jnp.sum(
-                active & (jnp.arange(w_cap) < w_sel))
+            with jax.named_scope("select"):
+                w_now = (jnp.minimum(jnp.float32(dw_max), w_dyn)
+                         if dynamic_width else jnp.float32(width))
+                w_sel = jnp.minimum(w_now,
+                                    jnp.float32(width)).astype(jnp.int32)
+                fidx, active = top_w_unexpanded(
+                    keys[:, 0], flags[:, 0], ids < SENTINEL, w_cap,
+                    w_dynamic=(w_sel + (spec if pipeline else 0)))
+                # pipeline: the first w_sel are confirmed, the rest
+                # speculative
+                fids = jnp.where(active, ids[fidx], SENTINEL)
+                neff_m = neff_m + jnp.sum(
+                    active & (jnp.arange(w_cap) < w_sel))
 
-            # --- page fetch accounting --------------------------------------
-            safe_f = jnp.minimum(jnp.maximum(fids, 0), n - 1)
-            fpages = jnp.where(fids < SENTINEL, vid2page[safe_f], -1)
-            is_cached = (fids < SENTINEL) & cached[safe_f]
-            # unique non-cached pages this step
-            chargeable = jnp.where(is_cached, -1, fpages)
-            srt = jnp.sort(chargeable)
-            uniq = (srt >= 0) & jnp.concatenate(
-                [jnp.ones((1,), bool), srt[1:] != srt[:-1]])
-            pages_step = jnp.sum(uniq).astype(jnp.float32)
-            pages_m = pages_m + pages_step
-            cache_m = cache_m + jnp.sum(is_cached).astype(jnp.float32)
-            nread_m = nread_m + pages_step * n_p
-            if track_visited:
-                visited = visited.at[
-                    jnp.where(chargeable >= 0, chargeable, num_pages)].set(True)
-            if track_trace:
-                # the step's distinct charged pages, in one row of the trace
-                trace = trace.at[it].set(jnp.where(uniq, srt, -1))
+                # --- page fetch accounting ----------------------------------
+                safe_f = jnp.minimum(jnp.maximum(fids, 0), n - 1)
+                fpages = jnp.where(fids < SENTINEL, vid2page[safe_f], -1)
+                is_cached = (fids < SENTINEL) & cached[safe_f]
+                # unique non-cached pages this step
+                chargeable = jnp.where(is_cached, -1, fpages)
+                srt = jnp.sort(chargeable)
+                uniq = (srt >= 0) & jnp.concatenate(
+                    [jnp.ones((1,), bool), srt[1:] != srt[:-1]])
+                pages_step = jnp.sum(uniq).astype(jnp.float32)
+                pages_m = pages_m + pages_step
+                cache_m = cache_m + jnp.sum(is_cached).astype(jnp.float32)
+                nread_m = nread_m + pages_step * n_p
+                if track_visited:
+                    visited = visited.at[
+                        jnp.where(chargeable >= 0, chargeable,
+                                  num_pages)].set(True)
+                if track_trace:
+                    # the step's distinct charged pages, in one row of the
+                    # trace
+                    trace = trace.at[it].set(jnp.where(uniq, srt, -1))
 
             # --- fetch records ----------------------------------------------
-            pg = jnp.maximum(fpages, 0)
-            rec_vids = page_vids[pg]                    # (w_cap, n_p)
-            rec_vecs = page_vecs[pg]                    # (w_cap, n_p, d)
-            rec_nbrs = page_nbrs[pg, vid2slot[safe_f]]  # (w_cap, R)
-            page_ok = (fids < SENTINEL)
+            with jax.named_scope("page_gather"):
+                pg = jnp.maximum(fpages, 0)
+                rec_vids = page_vids[pg]                    # (w_cap, n_p)
+                rec_vecs = page_vecs[pg]                    # (w_cap, n_p, d)
+                rec_nbrs = page_nbrs[pg, vid2slot[safe_f]]  # (w_cap, R)
+                page_ok = (fids < SENTINEL)
 
-            # exact distance for every record on fetched pages
-            rd = jax.vmap(lambda vs: sq_dists(qv, vs))(rec_vecs)  # (w_cap,n_p)
-            rec_valid = (rec_vids >= 0) & page_ok[:, None]
-            full_m = full_m + jnp.sum(rec_valid).astype(jnp.float32)
+            with jax.named_scope("exact_dist"):
+                # exact distance for every record on fetched pages
+                rd = jax.vmap(lambda vs: sq_dists(qv, vs))(
+                    rec_vecs)                               # (w_cap, n_p)
+                rec_valid = (rec_vids >= 0) & page_ok[:, None]
+                full_m = full_m + jnp.sum(rec_valid).astype(jnp.float32)
 
-            # frontier's own exact distances (re-rank info, always used)
-            own = rec_vids == jnp.where(fids < SENTINEL, fids, -2)[:, None]
-            own_ids = jnp.where(page_ok, fids, SENTINEL)
-            own_d = jnp.where(page_ok,
-                              jnp.sum(jnp.where(own, rd, 0.0), 1), INF)
+                # frontier's own exact distances (re-rank info, always used)
+                own = rec_vids == jnp.where(fids < SENTINEL, fids,
+                                            -2)[:, None]
+                own_ids = jnp.where(page_ok, fids, SENTINEL)
+                own_d = jnp.where(page_ok,
+                                  jnp.sum(jnp.where(own, rd, 0.0), 1), INF)
 
             # --- assemble merge inputs --------------------------------------
-            parts_ids = [ids, own_ids]
-            parts_rank = [keys[:, 0], own_d]
-            parts_exact = [keys[:, 1], own_d]
-            parts_exp = [flags[:, 0], page_ok]
-            parts_exk = [flags[:, 1], page_ok]
+            with jax.named_scope("merge"):
+                parts_ids = [ids, own_ids]
+                parts_rank = [keys[:, 0], own_d]
+                parts_exact = [keys[:, 1], own_d]
+                parts_exp = [flags[:, 0], page_ok]
+                parts_exk = [flags[:, 1], page_ok]
 
-            if page_search:
-                pr_ids = jnp.where(rec_valid, rec_vids, SENTINEL).reshape(-1)
-                pr_d = jnp.where(rec_valid, rd, INF).reshape(-1)
-                parts_ids.append(pr_ids)
-                parts_rank.append(pr_d)
-                parts_exact.append(pr_d)
-                parts_exp.append(jnp.zeros_like(pr_ids, bool))
-                parts_exk.append(pr_ids < SENTINEL)
+                if page_search:
+                    pr_ids = jnp.where(rec_valid, rec_vids,
+                                       SENTINEL).reshape(-1)
+                    pr_d = jnp.where(rec_valid, rd, INF).reshape(-1)
+                    parts_ids.append(pr_ids)
+                    parts_rank.append(pr_d)
+                    parts_exact.append(pr_d)
+                    parts_exp.append(jnp.zeros_like(pr_ids, bool))
+                    parts_exk.append(pr_ids < SENTINEL)
 
-            nb = jnp.where(page_ok[:, None] & (rec_nbrs >= 0),
-                           rec_nbrs, SENTINEL).reshape(-1)
-            nb_pq = jnp.where(nb < SENTINEL, pq_dist(nb), INF)
-            pq_m_ = pq_m_ + jnp.sum(nb < SENTINEL).astype(jnp.float32)
-            parts_ids.append(nb)
-            parts_rank.append(nb_pq)
-            parts_exact.append(jnp.full_like(nb_pq, INF))
-            parts_exp.append(jnp.zeros_like(nb, bool))
-            parts_exk.append(jnp.zeros_like(nb, bool))
+                nb = jnp.where(page_ok[:, None] & (rec_nbrs >= 0),
+                               rec_nbrs, SENTINEL).reshape(-1)
+            nb_d = pq_dist(nb)
+            with jax.named_scope("merge"):
+                nb_pq = jnp.where(nb < SENTINEL, nb_d, INF)
+                pq_m_ = pq_m_ + jnp.sum(nb < SENTINEL).astype(jnp.float32)
+                parts_ids.append(nb)
+                parts_rank.append(nb_pq)
+                parts_exact.append(jnp.full_like(nb_pq, INF))
+                parts_exp.append(jnp.zeros_like(nb, bool))
+                parts_exk.append(jnp.zeros_like(nb, bool))
 
-            all_ids = jnp.concatenate(parts_ids)
-            all_keys = jnp.stack([jnp.concatenate(parts_rank),
-                                  jnp.concatenate(parts_exact)], 1)
-            all_flags = jnp.stack([jnp.concatenate(parts_exp),
-                                   jnp.concatenate(parts_exk)], 1)
-            ids, keys, flags = dedup_merge_topL(all_ids, all_keys, all_flags, L)
-            # expanded entries keep exact distance as ranking key
-            keys = keys.at[:, 0].set(
-                jnp.where(flags[:, 1], keys[:, 1], keys[:, 0]))
+                all_ids = jnp.concatenate(parts_ids)
+                all_keys = jnp.stack([jnp.concatenate(parts_rank),
+                                      jnp.concatenate(parts_exact)], 1)
+                all_flags = jnp.stack([jnp.concatenate(parts_exp),
+                                       jnp.concatenate(parts_exk)], 1)
+                ids, keys, flags = dedup_merge_topL(all_ids, all_keys,
+                                                    all_flags, L)
+                # expanded entries keep exact distance as ranking key
+                keys = keys.at[:, 0].set(
+                    jnp.where(flags[:, 1], keys[:, 1], keys[:, 0]))
 
-            # dynamic width phase detection: no improvement => converge phase
-            improved = keys[0, 0] < best_before
-            stall = jnp.where(improved, 0.0, stall + 1.0)
-            w_dyn = jnp.where(dynamic_width & (stall > 0),
-                              jnp.minimum(w_dyn * 2.0, jnp.float32(dw_max)),
-                              w_dyn)
+                # dynamic width phase detection: no improvement => converge
+                # phase
+                improved = keys[0, 0] < best_before
+                stall = jnp.where(improved, 0.0, stall + 1.0)
+                w_dyn = jnp.where(dynamic_width & (stall > 0),
+                                  jnp.minimum(w_dyn * 2.0,
+                                              jnp.float32(dw_max)),
+                                  w_dyn)
             return (ids, keys, flags, it + 1, w_dyn, stall, visited, trace,
                     pages_m, cache_m, nread_m, neff_m, full_m, pq_m_)
 
@@ -223,10 +250,11 @@ def _search_batch(page_vids, page_vecs, page_nbrs, vid2page, vid2slot,
         pages_m, cache_m, nread_m, neff_m, full_m, pq_m_ = out[8:14]
 
         # final top-k by exact distance (re-rank among exact-known)
-        final_key = jnp.where(flags[:, 1], keys[:, 1], INF)
-        order = jnp.argsort(final_key)[:k]
-        topk = jnp.where(final_key[order] < INF, ids[order], -1)
-        topd = final_key[order]
+        with jax.named_scope("rerank"):
+            final_key = jnp.where(flags[:, 1], keys[:, 1], INF)
+            order = jnp.argsort(final_key)[:k]
+            topk = jnp.where(final_key[order] < INF, ids[order], -1)
+            topd = final_key[order]
         out = {"ids": topk, "dists": topd, "hops": it,
                "page_reads": pages_m, "cache_hits": cache_m,
                "n_read": nread_m, "n_eff": neff_m,
@@ -354,6 +382,10 @@ def search_batched(store, pq, cfg, queries: np.ndarray, *,
     fused pipelined kernel: QueryStats.measured_step_us carries each query's
     measured kernel wall clock (its page count x the batch's measured
     per-page rate) next to the modeled device time.
+
+    Each batch's host work runs under the wall-clock spans
+    `ann.search.memgraph`, `ann.search.launch` and `ann.search.pull`
+    (repro.obs.span: recorded only while a profiler trace runs).
     """
     fused = cfg.pipeline == "fused"
     track_trace = collect_trace or fused
@@ -377,30 +409,33 @@ def search_batched(store, pq, cfg, queries: np.ndarray, *,
     for s in range(0, len(queries), batch):
         qb = np.asarray(queries[s:s + batch], np.float32)
         if memgraph is not None and cfg.memgraph_frac > 0:
-            mg = memgraph.entry_points(
-                qb, n_entries=cfg.memgraph_entries, L=cfg.memgraph_L)
+            with span("ann.search.memgraph"):
+                mg = memgraph.entry_points(
+                    qb, n_entries=cfg.memgraph_entries, L=cfg.memgraph_L)
             entries = mg["entries"]
             mem_hops, mem_evals = mg["hops"], mg["dist_evals"]
         else:
             entries = np.full((len(qb), 1), medoid, np.int32)
             mem_hops = np.zeros(len(qb), np.int32)
             mem_evals = np.zeros(len(qb), np.int32)
-        valid = entries >= 0
-        out = _search_batch(
-            vids, vecs, nbrs, v2p, v2s,
-            pq_cent, pq_codes, cached,
-            jnp.asarray(qb), jnp.asarray(entries), jnp.asarray(valid),
-            k=cfg.k, L=cfg.L, width=cfg.beam_width,
-            max_iters=cfg.max_iters, n_p=store.layout.n_p,
-            page_search=cfg.page_search,
-            dynamic_width=cfg.dynamic_width, dw_min=cfg.dw_min,
-            dw_max=cfg.dw_max, pipeline=cfg.pipeline,
-            spec=cfg.pipeline_spec, track_visited=collect_visited,
-            track_trace=track_trace)
-        out = {k_: np.asarray(v) for k_, v in out.items()}
-        out["mem_hops"] = mem_hops
-        out["mem_evals"] = mem_evals
-        st = QueryStats.from_kernel(out)
+        with span("ann.search.launch"):
+            valid = entries >= 0
+            out = _search_batch(
+                vids, vecs, nbrs, v2p, v2s,
+                pq_cent, pq_codes, cached,
+                jnp.asarray(qb), jnp.asarray(entries), jnp.asarray(valid),
+                k=cfg.k, L=cfg.L, width=cfg.beam_width,
+                max_iters=cfg.max_iters, n_p=store.layout.n_p,
+                page_search=cfg.page_search,
+                dynamic_width=cfg.dynamic_width, dw_min=cfg.dw_min,
+                dw_max=cfg.dw_max, pipeline=cfg.pipeline,
+                spec=cfg.pipeline_spec, track_visited=collect_visited,
+                track_trace=track_trace)
+        with span("ann.search.pull"):
+            out = {k_: np.asarray(v) for k_, v in out.items()}
+            out["mem_hops"] = mem_hops
+            out["mem_evals"] = mem_evals
+            st = QueryStats.from_kernel(out)
         if fused:
             m = measure_step_us(store, pq, qb, out["page_trace"])
             st.measured_step_us = (st.page_reads.astype(np.float64)

@@ -1,9 +1,7 @@
 """Metrics primitives for the serving stack.
 
 One implementation backs every report percentile: a log-bucketed
-``Histogram`` with a documented multiplicative error bound, plus the
-usual monotone ``Counter`` and last-write ``Gauge``, collected in a
-``MetricsRegistry``.
+``Histogram`` with a documented multiplicative error bound.
 
 Design notes
 ------------
@@ -23,16 +21,12 @@ relies on (no silently fabricated ``0.0`` latencies).
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Tuple, Union
+from typing import Dict, Iterable, Union
 
 import numpy as np
 
 __all__ = [
-    "Counter",
-    "Gauge",
     "Histogram",
-    "MetricsRegistry",
     "DEFAULT_GROWTH",
     "DEFAULT_LO",
 ]
@@ -43,36 +37,6 @@ __all__ = [
 DEFAULT_GROWTH = 1.002
 # Values at or below ``lo`` share bucket 0 (reported as the observed min).
 DEFAULT_LO = 1e-3
-
-
-@dataclass
-class Counter:
-    """Monotone event counter."""
-
-    name: str
-    value: float = 0.0
-
-    def inc(self, n: Union[int, float] = 1) -> None:
-        if n < 0:
-            raise ValueError(f"counter {self.name!r} cannot decrease (n={n})")
-        self.value += n
-
-    def snapshot(self) -> Dict[str, float]:
-        return {"value": float(self.value)}
-
-
-@dataclass
-class Gauge:
-    """Last-write-wins instantaneous value."""
-
-    name: str
-    value: float = float("nan")
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
-
-    def snapshot(self) -> Dict[str, float]:
-        return {"value": float(self.value)}
 
 
 class Histogram:
@@ -226,47 +190,3 @@ class Histogram:
             "p99": self.quantile(0.99),
             "max": self.max,
         }
-
-
-@dataclass
-class MetricsRegistry:
-    """Name-keyed get-or-create store for Counters, Gauges, Histograms."""
-
-    _metrics: Dict[str, Union[Counter, Gauge, Histogram]] = field(
-        default_factory=dict)
-
-    def _get(self, name: str, kind: type,
-             factory) -> Union[Counter, Gauge, Histogram]:
-        m = self._metrics.get(name)
-        if m is None:
-            m = factory()
-            self._metrics[name] = m
-        elif not isinstance(m, kind):
-            raise TypeError(
-                f"metric {name!r} already registered as "
-                f"{type(m).__name__}, not {kind.__name__}")
-        return m
-
-    def counter(self, name: str) -> Counter:
-        m = self._get(name, Counter, lambda: Counter(name))
-        assert isinstance(m, Counter)
-        return m
-
-    def gauge(self, name: str) -> Gauge:
-        m = self._get(name, Gauge, lambda: Gauge(name))
-        assert isinstance(m, Gauge)
-        return m
-
-    def histogram(self, name: str, growth: float = DEFAULT_GROWTH,
-                  lo: float = DEFAULT_LO) -> Histogram:
-        m = self._get(name, Histogram,
-                      lambda: Histogram(name, growth=growth, lo=lo))
-        assert isinstance(m, Histogram)
-        return m
-
-    def names(self) -> List[str]:
-        return sorted(self._metrics)
-
-    def as_dict(self) -> Dict[str, Dict[str, float]]:
-        return {name: self._metrics[name].snapshot()
-                for name in self.names()}

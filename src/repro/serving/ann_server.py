@@ -101,7 +101,7 @@ from repro.core.search_kernel import search_batched
 from repro.core.stats import QueryStats
 from repro.io import DYNAMIC_POLICIES, PLACEMENTS, build_store
 from repro.mutation import Compactor, MutableIndex, MutationMix
-from repro.obs import Histogram, Tracer
+from repro.obs import Histogram, Tracer, span
 from repro.serving.admission import AdmissionConfig, AdmissionController
 
 
@@ -557,6 +557,7 @@ class AnnServer:
             # charge its books, not just the facade's
             index.attach_store(self.store)
         self._degraded_cfgs = {}    # degrade level -> SearchConfig
+        self._serial = 0            # closed-loop batches, for span tags
 
     # -- batch executor ------------------------------------------------------
 
@@ -725,48 +726,50 @@ class AnnServer:
         fleet's (B, R, S) replica grid — this batch's pages on replica r's
         row, zero elsewhere — so the device time is priced by the model's
         max-over-replicas-then-shards path."""
-        store = store if store is not None else self.store
-        if self._stateful:
-            acct = store.replay_batch(stats.page_trace,
-                                      tenants=stats.tenants)
-            pages = acct["per_query_issued"]
-            dedup, overlap = 1.0, acct["overlap_frac"]
-        else:
-            acct = store.coalesce(stats.visited_pages)
-            acct.setdefault("hits", 0)
-            acct["overlap_frac"] = overlap = 0.0
-            requested, issued = acct["requested"], acct["issued"]
-            dedup = issued / requested if requested else 1.0
-            # the batch store holds a page for the whole batch, so each query
-            # is charged its DISTINCT pages (step revisits are buffer hits),
-            # scaled by the coalescing rebate: charges sum to the union
-            pages = stats.visited_pages.sum(axis=1).astype(np.float64)
-        sp = acct.get("per_query_shard_pages")
-        sd = acct.get("shard_depths")
-        if lift is not None:
-            r, R = lift
-            if sp is None:
-                # unsharded replica: its whole device is one (r, s) cell
-                sp = np.asarray(pages, np.float64)[:, None]
-                sd = np.asarray([depth], np.float64)
-            S = sp.shape[1]
-            grid = np.zeros((len(sp), R, S), np.float64)
-            grid[:, r, :] = sp
-            depths = np.zeros((R, S), np.float64)
-            depths[r] = np.asarray(sd, np.float64)
-            sp, sd = grid, depths
-        lat = self.model.concurrent_latency_us(
-            depth,
-            hops=stats.hops.astype(np.float64),
-            pages=pages,
-            full_evals=stats.full_evals.astype(np.float64),
-            pq_evals=stats.pq_evals.astype(np.float64),
-            mem_evals=stats.mem_evals.astype(np.float64),
-            d=d, pq_m=self.cfg.pq_m, page_bytes=self.cfg.page_bytes,
-            pipeline=self.cfg.pipeline, page_dedup=dedup,
-            prefetch_overlap=overlap,
-            shard_pages=sp, shard_depths=sd)
-        return np.asarray(lat, np.float64), acct
+        with span("ann.serve.price"):
+            store = store if store is not None else self.store
+            if self._stateful:
+                acct = store.replay_batch(stats.page_trace,
+                                          tenants=stats.tenants)
+                pages = acct["per_query_issued"]
+                dedup, overlap = 1.0, acct["overlap_frac"]
+            else:
+                acct = store.coalesce(stats.visited_pages)
+                acct.setdefault("hits", 0)
+                acct["overlap_frac"] = overlap = 0.0
+                requested, issued = acct["requested"], acct["issued"]
+                dedup = issued / requested if requested else 1.0
+                # the batch store holds a page for the whole batch, so each
+                # query is charged its DISTINCT pages (step revisits are
+                # buffer hits), scaled by the coalescing rebate: charges sum
+                # to the union
+                pages = stats.visited_pages.sum(axis=1).astype(np.float64)
+            sp = acct.get("per_query_shard_pages")
+            sd = acct.get("shard_depths")
+            if lift is not None:
+                r, R = lift
+                if sp is None:
+                    # unsharded replica: its whole device is one (r, s) cell
+                    sp = np.asarray(pages, np.float64)[:, None]
+                    sd = np.asarray([depth], np.float64)
+                S = sp.shape[1]
+                grid = np.zeros((len(sp), R, S), np.float64)
+                grid[:, r, :] = sp
+                depths = np.zeros((R, S), np.float64)
+                depths[r] = np.asarray(sd, np.float64)
+                sp, sd = grid, depths
+            lat = self.model.concurrent_latency_us(
+                depth,
+                hops=stats.hops.astype(np.float64),
+                pages=pages,
+                full_evals=stats.full_evals.astype(np.float64),
+                pq_evals=stats.pq_evals.astype(np.float64),
+                mem_evals=stats.mem_evals.astype(np.float64),
+                d=d, pq_m=self.cfg.pq_m, page_bytes=self.cfg.page_bytes,
+                pipeline=self.cfg.pipeline, page_dedup=dedup,
+                prefetch_overlap=overlap,
+                shard_pages=sp, shard_depths=sd)
+            return np.asarray(lat, np.float64), acct
 
     def _trace_batch(self, tracer: Tracer, pid: int, dispatch: float,
                      lat: np.ndarray, acct: dict, stats: QueryStats,
@@ -899,55 +902,60 @@ class AnnServer:
                     and events[0][0] <= dispatch:
                 batch.append(heapq.heappop(events))
 
-            qvecs = queries[[q for _, _, q in batch]]
-            stats = self._execute(qvecs)
-            stats.tenants = tenant_of[[q for _, _, q in batch]]
-            # device queue depth = queries in flight in this batch
-            lat, acct = self._batch_times_us(stats, len(batch), d)
-            requested_total += acct["requested"]
-            issued_total += acct["issued"]
-            hits_total += acct["hits"]
-            overlap_w += acct["overlap_frac"] * acct["issued"]
-            shard_win.add(acct)
-            done = dispatch + lat
-            exec_free = dispatch + float(lat.max())
-            t_end = max(t_end, exec_free)
-            batch_sizes.append(len(batch))
-            for (t_sub, c, q), t_done in zip(batch, done):
-                lat_out.append(t_done - t_sub)
-                service_out.append(t_done - dispatch)
-                qidx_out.append(q)
-                tenant_out.append(int(tenant_of[q]))
-                if issued[c] < rounds:
-                    nxt = (c + issued[c] * workers) % len(queries)
-                    heapq.heappush(events, (float(t_done), c, nxt))
-                    issued[c] += 1
-            stats_out.append(stats)
+            self._serial += 1
+            with span("ann.serve.batch", batch=self._serial,
+                      size=len(batch)):
+                qvecs = queries[[q for _, _, q in batch]]
+                stats = self._execute(qvecs)
+                stats.tenants = tenant_of[[q for _, _, q in batch]]
+                # device queue depth = queries in flight in this batch
+                lat, acct = self._batch_times_us(stats, len(batch), d)
+                requested_total += acct["requested"]
+                issued_total += acct["issued"]
+                hits_total += acct["hits"]
+                overlap_w += acct["overlap_frac"] * acct["issued"]
+                shard_win.add(acct)
+                done = dispatch + lat
+                exec_free = dispatch + float(lat.max())
+                t_end = max(t_end, exec_free)
+                batch_sizes.append(len(batch))
+                for (t_sub, c, q), t_done in zip(batch, done):
+                    lat_out.append(t_done - t_sub)
+                    service_out.append(t_done - dispatch)
+                    qidx_out.append(q)
+                    tenant_out.append(int(tenant_of[q]))
+                    if issued[c] < rounds:
+                        nxt = (c + issued[c] * workers) % len(queries)
+                        heapq.heappush(events, (float(t_done), c, nxt))
+                        issued[c] += 1
+                stats_out.append(stats)
 
-        all_stats = QueryStats.concat(stats_out)
-        lat_arr = np.asarray(lat_out)
-        _, lat_mean, lat_p50, lat_p99 = _latency_summary(lat_arr)
-        return ServingReport(
-            workers=workers, queries=total, elapsed_us=t_end,
-            qps=total / (t_end * 1e-6) if t_end > 0 else 0.0,
-            mean_latency_us=lat_mean,
-            p50_latency_us=lat_p50,
-            p99_latency_us=lat_p99,
-            mean_service_us=float(np.mean(service_out)),
-            mean_batch_size=float(np.mean(batch_sizes)),
-            pages_per_query=float(all_stats.page_reads.mean()),
-            batched_pages_per_query=issued_total / total,
-            dedup_saved_frac=(1.0 - issued_total / requested_total
-                              if requested_total else 0.0),
-            stats=all_stats,
-            query_indices=np.asarray(qidx_out, np.int64),
-            cache_hit_rate=(hits_total / requested_total
-                            if requested_total else 0.0),
-            overlap_frac=(overlap_w / issued_total if issued_total else 0.0),
-            measured_step_us=_measured_step(all_stats),
-            per_tenant=(self._per_tenant_report(tenant_out, lat_arr)
-                        if multi_tenant else None),
-            per_shard=shard_win.report(t_end))
+        with span("ann.serve.report"):
+            all_stats = QueryStats.concat(stats_out)
+            lat_arr = np.asarray(lat_out)
+            _, lat_mean, lat_p50, lat_p99 = _latency_summary(lat_arr)
+            return ServingReport(
+                workers=workers, queries=total, elapsed_us=t_end,
+                qps=total / (t_end * 1e-6) if t_end > 0 else 0.0,
+                mean_latency_us=lat_mean,
+                p50_latency_us=lat_p50,
+                p99_latency_us=lat_p99,
+                mean_service_us=float(np.mean(service_out)),
+                mean_batch_size=float(np.mean(batch_sizes)),
+                pages_per_query=float(all_stats.page_reads.mean()),
+                batched_pages_per_query=issued_total / total,
+                dedup_saved_frac=(1.0 - issued_total / requested_total
+                                  if requested_total else 0.0),
+                stats=all_stats,
+                query_indices=np.asarray(qidx_out, np.int64),
+                cache_hit_rate=(hits_total / requested_total
+                                if requested_total else 0.0),
+                overlap_frac=(overlap_w / issued_total
+                              if issued_total else 0.0),
+                measured_step_us=_measured_step(all_stats),
+                per_tenant=(self._per_tenant_report(tenant_out, lat_arr)
+                            if multi_tenant else None),
+                per_shard=shard_win.report(t_end))
 
     # -- open loop -----------------------------------------------------------
 

@@ -1,6 +1,6 @@
-"""Observability: metrics primitives, the span tracer, Chrome trace
-export, and the latency-attribution conservation contract on the
-serving loops.
+"""Observability: the latency histogram, the span tracer, Chrome trace
+export, the latency-attribution conservation contract on the serving
+loops, and the wall-clock spans on the profiler's clock.
 
 The load-bearing claims: (1) histogram p50/p99 agree with the order
 statistic ``np.percentile(..., method="higher")`` within the documented
@@ -8,15 +8,17 @@ statistic ``np.percentile(..., method="higher")`` within the documented
 to the reported latency exactly, and per-shard device spans reproduce
 the shard window's busy time; (3) the exported Chrome trace validates
 (well-formed, async spans balanced, flows resolve); (4) tracing off is
-invisible — identical reports, zero recorded state.
+invisible — identical reports, zero recorded state; (5) wall-clock spans
+record nothing without a profiler, land in the profiler's trace with the
+batch's tags under one, and leave search results bit-identical.
 """
 import numpy as np
 import pytest
 
 from repro import sanitize
 from repro.core import get_preset
-from repro.obs import (CONSERVATION_TOL_US, Counter, Gauge, Histogram,
-                       MetricsRegistry, Tracer, validate_chrome_trace)
+from repro.obs import (CONSERVATION_TOL_US, Histogram, Tracer, span,
+                       validate_chrome_trace)
 from repro.serving.ann_server import (AnnServer, ServerConfig,
                                       _latency_summary)
 from repro.serving.fleet import FleetConfig, FleetServer
@@ -56,19 +58,11 @@ def test_histogram_merge_and_registry_contracts():
     b = Histogram.from_values([10.0, 20.0])
     a.merge(b)
     assert a.count == 5 and a.max == 20.0 and a.min == 1.0
-    reg = MetricsRegistry()
-    reg.counter("n").inc(3)
-    reg.gauge("depth").set(2.5)
-    reg.histogram("lat").observe(7.0)
-    assert isinstance(reg.counter("n"), Counter)
-    assert isinstance(reg.gauge("depth"), Gauge)
-    assert reg.counter("n").value == 3
-    with pytest.raises(TypeError):
-        reg.gauge("n")            # name already taken by a Counter
+    assert a.total == pytest.approx(36.0)
+    for q, v in ((0.0, 1.0), (1.0, 20.0)):
+        assert abs(a.quantile(q) - v) / v <= a.error_bound
     with pytest.raises(ValueError):
-        reg.counter("n").inc(-1)  # counters are monotone
-    assert reg.names() == ["depth", "lat", "n"]
-    assert set(reg.as_dict()) == {"n", "depth", "lat"}
+        a.merge(Histogram(growth=1.01))   # other buckets: no merge
 
 
 def test_latency_summary_empty_is_finite_and_schema_stable():
@@ -209,3 +203,76 @@ def test_fleet_traced_run_conserves_and_validates(base_index,
     assert validate_chrome_trace(tracer.to_chrome()) == []
     # spans landed on both replica groups' lanes
     assert {sp.pid for sp in tracer.spans if sp.cat == "batch"} == {0, 1}
+
+
+# --- wall-clock spans on the profiler's clock -------------------------------
+
+
+def _profiled(tmp_path, fn):
+    """Run fn() under the profiler; (fn's result, host events by name as
+    [(args, duration_ns)])."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    pd = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    events = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(
+                    (dict(ev.stats), ev.duration_ns))
+    return out, events
+
+
+def test_spans_record_nothing_without_a_profiler(tmp_path):
+    with span("ann.before", batch=5):         # no profiler runs yet
+        pass
+
+    def work():
+        with span("ann.during", batch=6):
+            return 1
+    _, events = _profiled(tmp_path, work)
+    assert [n for n in events if n.startswith("ann.")] == ["ann.during"]
+    assert events["ann.during"][0][0] == {"batch": 6}
+
+
+def test_spans_land_in_the_profile_with_batch_tags(tmp_path, base_index,
+                                                   small_dataset):
+    srv = AnnServer(base_index, get_preset("baseline", L=16))
+    q = small_dataset.queries[:8]
+    srv.serve_closed_loop(q, workers=8)             # compile outside
+    _, events = _profiled(
+        tmp_path, lambda: [srv.serve_closed_loop(q, workers=8)
+                           for _ in range(2)])
+    batches = [a for a, _ in events["ann.serve.batch"]]
+    assert batches == [{"batch": 2, "size": 8}, {"batch": 3, "size": 8}]
+    for name in ("ann.search.launch", "ann.search.pull", "ann.serve.price",
+                 "ann.serve.report"):
+        assert len(events[name]) == 2, name
+        assert all(d > 0 for _, d in events[name]), name
+    assert "ann.search.memgraph" not in events      # baseline: no MemGraph
+
+
+def test_search_batched_bit_identical_with_and_without_profiler(
+        tmp_path, base_index, small_dataset):
+    from repro.core.search_kernel import search_batched
+    from repro.io import build_store
+    store = build_store(base_index.layout, batched=True)
+    cfg = get_preset("baseline", L=24)
+
+    def run():
+        return search_batched(store, base_index.pq, cfg,
+                              small_dataset.queries, batch=16,
+                              medoid=base_index.medoid)
+    off = run()
+    on, events = _profiled(tmp_path, run)
+    n = len(small_dataset.queries) // 16
+    assert len(events["ann.search.pull"]) == n
+    for f in ("ids", "dists", "hops", "page_reads", "pq_evals",
+              "full_evals", "visited_pages"):
+        a, b = getattr(off, f), getattr(on, f)
+        assert a.dtype == b.dtype and np.array_equal(a, b), f
