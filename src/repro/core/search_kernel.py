@@ -23,6 +23,15 @@ The kernel's stages run under `jax.named_scope` — `pq_lut`, `pq_lookup`,
 `select`, `page_gather`, `exact_dist`, `merge`, `rerank` — so each device
 op's `op_name` metadata names its stage; scopes change no arithmetic.
 
+The ADC table lookup under `pq_lookup` has one result in two forms,
+picked per platform when the step is lowered (`jax.lax.platform_dependent`):
+the TPU runs `adc_lookup_select`, a compare of each code with the 256
+centroid indices and a max over them, since its gather of single table
+elements took about four fifths of the chip's time in the step; every other
+platform runs `adc_lookup_gather`, which the CPU does natively (0.6 ms
+against the select's 313 ms for 16 queries x 2,048 neighbours x 16
+sub-quantizers on a Xeon CPU). Both give the same values, bit for bit.
+
 Technique mapping (SearchConfig):
   PQ            — always on (the paper's §6 baseline): neighbors ranked by
                   memory-resident ADC distances; exact distances only for
@@ -59,6 +68,24 @@ from repro.core.stats import QueryStats
 from repro.obs import span
 
 
+def adc_lookup_gather(lut, codes):
+    """ADC table lookup `lut[m, codes[n, m]]`, (N, M), as one gather of
+    table elements: lut (M, 256) f32, codes (N, M) uint8. The CPU's form."""
+    return jnp.take_along_axis(lut.T, codes.astype(jnp.int32), axis=0)
+
+
+def adc_lookup_select(lut, codes):
+    """The same (N, M) values as `adc_lookup_gather`, bit for bit, without a
+    gather: each code is compared with every centroid index and the hit
+    entry kept by a max over the 256 axis (the table holds squared
+    distances, all >= 0, so a fill of 0 is exact). The 256 axis is major
+    and reduced inside one fusion, so N stays in lanes and the
+    (256, M, N) select is never materialized. The TPU's form."""
+    hit = (codes.T.astype(jnp.int32)[None]
+           == jnp.arange(lut.shape[1], dtype=jnp.int32)[:, None, None])
+    return jnp.max(jnp.where(hit, lut.T[:, :, None], 0.0), axis=0).T
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("k", "L", "width", "max_iters", "n_p", "page_search",
@@ -85,10 +112,10 @@ def _search_batch(page_vids, page_vecs, page_nbrs, vid2page, vid2slot,
         def pq_dist(ids):
             with jax.named_scope("pq_lookup"):
                 safe = jnp.minimum(jnp.maximum(ids, 0), n - 1)
-                codes = pq_codes[safe]                  # (.., M)
-                d = jnp.take_along_axis(
-                    lut.T, codes.astype(jnp.int32), axis=0)  # broadcast gather
-                # lut.T is (256, M); gather rows by code per column
+                codes = pq_codes[safe]                  # (N, M)
+                d = jax.lax.platform_dependent(
+                    lut, codes, tpu=adc_lookup_select,
+                    default=adc_lookup_gather)          # (N, M)
                 return jnp.sum(d, axis=-1)
 
         # candidate list: keys = [rank_key, exact_dist]; flags = [expanded,

@@ -6,6 +6,9 @@ a kernel may use). These tests compile the four Pallas kernels at the
 sift-like and deep-like page widths (4 KB pages, R=64, M=16, Q=256) and the
 beam-search step at real widths over a small page count, all with the
 Mosaic path (`interpret=False`), for one chip of a described `v5e:2x2`.
+The beam-search test also checks that the chip's program looks up the ADC
+table without a gather of its f32 elements, while the CPU's keeps that
+gather.
 
 The topology is described inside a fixture: the TPU library may be loaded
 by one process at a time, so no module of the suite touches it at import.
@@ -13,6 +16,7 @@ The last test checks that chip_smoke.py, the run on a real chip, refuses a
 host without one.
 """
 import importlib.util
+import re
 from pathlib import Path
 
 import jax
@@ -132,6 +136,29 @@ def test_search_batch_compiles_for_v5e(one_chip, preset):
     assert mem.argument_size_in_bytes > 0
     # the whole step fits one v5e's 16 GB of HBM with room to spare
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
+    # the ADC table lookup is a select over the centroids on the chip: no
+    # gather of f32 table elements; the uint8 row gather of codes stays
+    gathers = _pq_lookup_gathers(compiled.as_text())
+    assert "u8" in gathers and "f32" not in gathers
+    # the CPU lowering of the same step keeps its gather of table elements
+    cpu_args = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
+    assert "f32" in _pq_lookup_gathers(_compile(step, *cpu_args).as_text())
+
+
+HLO_LINE = re.compile(r'^\s*(?:ROOT\s+)?%?\S+ = (\w+)\[.*?'
+                      r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def _pq_lookup_gathers(hlo_text):
+    """Element types of the instructions that come from a `gather` under
+    the `pq_lookup` scope, fused computations included."""
+    found = set()
+    for line in hlo_text.splitlines():
+        m = HLO_LINE.match(line)
+        if m and "pq_lookup" in m.group(2) \
+                and m.group(2).rsplit("/", 1)[-1] == "gather":
+            found.add(m.group(1))
+    return found
 
 
 def test_chip_smoke_refuses_a_host_without_a_tpu():
