@@ -8,7 +8,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from bench.load import Call
+from bench.load import Call, Step
 from bench.trace_reduce import Reduction
 
 
@@ -25,6 +25,8 @@ class Ctx:
     pq_m: int                    # PQ sub-quantizers (bytes per code)
     red: Optional[Reduction] = None   # the trace, in a --trace 1 run
     peaks: Optional[dict] = None      # bench/peaks.json entry of the chip
+    steps: List[Step] = dataclasses.field(default_factory=list)
+    #                                   the window's mutation steps (stream)
 
     def per_request(self, field: str) -> np.ndarray:
         return np.concatenate([np.asarray(c.out[field]) for c in self.calls])

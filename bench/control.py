@@ -121,7 +121,7 @@ def main(argv=None):
 
     def built(data_seed):
         t0 = time.perf_counter()
-        base, pool, index, build_s = harness.build(cfg, data_seed)
+        base, pool, _, index, build_s = harness.build(cfg, data_seed)
         emit(data_seed=data_seed, n=cfg["n"], build_s=build_s,
              data_and_build_s=time.perf_counter() - t0,
              stats=index.build_stats)

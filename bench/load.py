@@ -11,6 +11,11 @@ what it wants printed about the run.
 The seed orders the queries within each round of the pool
 (`pool_order`); it never changes which queries a window draws, bar the last
 round, so every seed does the same work in another order.
+
+Over a mutating configuration the entry also carries `entry.stream`, the
+run's live set (bench/live.py): a generator that mutates applies its steps
+through it, stamps each search Call with the live set's `version`, and
+returns its `Step` records in `info["steps"]`.
 """
 from __future__ import annotations
 
@@ -31,6 +36,17 @@ class Call:
     pool_idx: np.ndarray       # (b,) query-pool rows served
     arrivals: np.ndarray       # (b,) scheduled arrival of each request
     out: dict                  # per-request arrays from the entry
+    version: int = 0           # mutations acknowledged before the call
+
+
+@dataclasses.dataclass
+class Step:
+    start: float               # the mutation step began
+    end: float                 # the step's acknowledgements returned
+    inserts: int               # inserts acknowledged
+    deletes: int               # deletes that took
+    flushes: int               # delta flushes the step set off
+    compactions: int           # compaction runs the step set off
 
 
 def annotate(trace: bool, name: str):

@@ -26,6 +26,23 @@ One process, on the machine that holds the chip:
    decides `correct` (bench/checks.py), over every request served.
 6. The metrics the cell lists, each from its reader in bench/metrics/.
 
+A configuration with a `stream` block mutates the index while it serves:
+
+- set-up draws its `inserts` rows with the base set and holds them back
+  from the build, wraps the built index in the program's MutableIndex
+  (`stream.mutation`: the fields of repro.mutation.MutationConfig) and
+  builds the one AnnServer over it;
+- the mutation entry (`make_mutate`) runs serve_open_loop's ingest steps
+  under the configuration's compaction policy (`stream.compaction`), and
+  the live set (bench/live.py) keeps the books; the traffic mix's generator
+  applies its steps through them inside the window;
+- after the window, outside it and before the program's state is freed,
+  the rows inserted in it and still live, and the rows deleted in it, are
+  queried through the same entry (a sample of at most READ_BACK_MAX each,
+  drawn from --seed);
+- the reference is the exact top-k over the vids live at each request's
+  version, and bench/checks.py judges the run with `judge_live`.
+
 The last lines on stderr give each number compared beside its limit; the
 last line on stdout is the result as one JSON object.
 """
@@ -46,6 +63,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 OUT = BENCH / "out"
 TRACE_SECONDS = 10.0
+READ_BACK_MAX = 1024
 RESULT_FIELDS = ("ids", "dists", "hops", "page_reads", "pq_evals",
                  "full_evals", "mem_hops")
 
@@ -133,12 +151,20 @@ def load_peaks(dev, rehearse: bool):
 
 
 def build(cfg: dict, seed: int):
-    """(base, pool, index, seconds): the data from `seed` and the program's
-    index over it, built with the same seed."""
+    """(base, pool, held, index, seconds): the data from `seed` and the
+    program's index over the base, built with the same seed. With a `stream`
+    block the last `inserts` rows drawn are held back from the build
+    (`held`) and the index is wrapped in the program's MutableIndex; without
+    one `held` is empty."""
     import numpy as np
     from repro.core import Dataset, build_index, get_preset
     from bench import data
-    base, pool = data.make(cfg["dataset"], cfg["n"], cfg["pool"], seed)
+    stream = cfg.get("stream")
+    n = cfg["n"]
+    base, pool = data.make(cfg["dataset"],
+                           n + (stream["inserts"] if stream else 0),
+                           cfg["pool"], seed)
+    base, held = base[:n], base[n:]
     ds = Dataset(cfg["dataset"]["name"], base, pool,
                  np.zeros((len(pool), 0), np.int32), cfg["dataset"]["dtype"])
     b = cfg["build"]
@@ -146,19 +172,78 @@ def build(cfg: dict, seed: int):
     index = build_index(ds, get_preset(cfg["preset"]), R=b["R"],
                         L_build=b["L_build"], alpha=b["alpha"],
                         seed=seed % (2 ** 31 - 1))
-    return base, pool, index, time.perf_counter() - t0
+    if stream:
+        from repro.mutation import MutableIndex, MutationConfig
+        index = MutableIndex(index, MutationConfig(**stream["mutation"]))
+    return base, pool, held, index, time.perf_counter() - t0
 
 
 def make_entry(server, pool):
-    """The timed path: one synchronous call of the server per batch."""
+    """The timed path: one synchronous call of the server per batch of pool
+    rows; `entry.search` takes the vectors themselves."""
     import numpy as np
 
-    def entry(idx):
-        rep = server.serve_closed_loop(pool[idx], workers=len(idx))
+    def search(vecs):
+        rep = server.serve_closed_loop(vecs, workers=len(vecs))
         inv = np.argsort(rep.query_indices, kind="stable")
         return {f: np.asarray(getattr(rep.stats, f))[inv]
                 for f in RESULT_FIELDS}
+
+    def entry(idx):
+        return search(pool[idx])
+    entry.search = search
     return entry
+
+
+def make_mutate(server, stream: dict):
+    """The mutation entry, (mutate, after_batch): the steps of
+    AnnServer.serve_open_loop's ingest, run synchronously on the server's
+    MutableIndex. An insert is followed by `maybe_flush` and the compaction
+    policy's `after_mutation`, a delete by `after_mutation`; `after_batch`
+    is the policy's hook after each served batch. The harness reaches the
+    program's mutations through these two alone."""
+    from repro.mutation import Compactor, MutationMix
+    c = stream["compaction"]
+    index = server.index
+    compactor = Compactor(index, MutationMix(
+        compaction=c["policy"], threshold=c["threshold"],
+        max_pages=c["max_pages"]))
+
+    def mutate(insert_vecs, delete_vids):
+        vids, took, flushes, compactions = [], [], 0, 0
+        for vec in insert_vecs:
+            vids.append(index.insert(vec))
+            flushes += index.maybe_flush() is not None
+            compactions += compactor.after_mutation() is not None
+        for vid in delete_vids:
+            took.append(index.delete(int(vid)))
+            compactions += compactor.after_mutation() is not None
+        return {"vids": vids, "took": took, "flushes": flushes,
+                "compactions": compactions}
+
+    def after_batch():
+        return int(compactor.after_batch() is not None)
+    return mutate, after_batch
+
+
+def read_back(entry, live, max_batch: int, seed: int) -> dict:
+    """After the window: the vectors of a sample (at most READ_BACK_MAX,
+    drawn from `seed`) of the rows inserted in it and still live, and of the
+    rows deleted in it, queried through the entry in batches of max_batch.
+    Returns {"inserted": (vids, ids), "deleted": (vids, ids)}."""
+    import numpy as np
+    rng = np.random.default_rng([seed, 4])
+    vectors = live.books()[0]
+    out = {}
+    for kind, vids in (("inserted", live.inserted_live()),
+                       ("deleted", live.deleted())):
+        if len(vids) > READ_BACK_MAX:
+            vids = np.sort(rng.choice(vids, READ_BACK_MAX, replace=False))
+        ids = [entry.search(vectors[vids[s:s + max_batch]])["ids"]
+               for s in range(0, len(vids), max_batch)]
+        out[kind] = (vids, np.concatenate(ids) if ids
+                     else np.zeros((0, 1), np.int64))
+    return out
 
 
 def run_window(mix, entry, pool_size, max_batch, seconds, seed, trace):
@@ -214,10 +299,15 @@ def main(argv=None) -> int:
     from bench import checks, reference
     from bench.context import Ctx
 
-    base, pool, index, build_s = build(cfg, cfg["data_seed"])
+    base, pool, held, index, build_s = build(cfg, cfg["data_seed"])
     server = AnnServer(index, index.cfg.replace(L=cfg["L"]))
     max_batch = server.server_cfg.max_batch
     entry = make_entry(server, pool)
+    stream = cfg.get("stream")
+    if stream:
+        from bench.live import LiveSet
+        entry.stream = live = LiveSet(base, held, cfg["data_seed"],
+                                      *make_mutate(server, stream))
     t0 = time.perf_counter()
     entry(np.arange(max_batch) % len(pool))
     warmup_s = time.perf_counter() - t0
@@ -229,9 +319,16 @@ def main(argv=None) -> int:
     calls, info = run_window(mix, entry, len(pool), max_batch, seconds,
                              args.seed, bool(args.trace))
     after = counter.snapshot()
+    steps = info.pop("steps", [])
+    if stream:
+        info.update(live.summary(), steps=len(steps),
+                    mutate_s=sum(st.end - st.start for st in steps))
+        back = read_back(entry, live, max_batch, args.seed)
 
     mem = dev.memory_stats() or {}
     peak_bytes = int(mem.get("peak_bytes_in_use", 0))
+    if stream:
+        live.detach()
     del server, index, entry
     gc.collect()
     red = reduce_trace(info.pop("trace_dir")) if args.trace else None
@@ -239,17 +336,35 @@ def main(argv=None) -> int:
     rows = np.concatenate([c.pool_idx for c in calls])
     out = {f: np.concatenate([c.out[f] for c in calls])
            for f in ("ids", "dists")}
-    uniq, inv = np.unique(rows, return_inverse=True)
     k = cfg["guarantees"]["k"]
-    ref_ids = reference.exact_topk(base, pool[uniq], k)[0][inv]
-    found, failed = checks.judge(base, pool[rows], out["ids"], out["dists"],
-                                 ref_ids, cfg["guarantees"])
+    attempted = len(rows)
+    if stream:
+        books = live.books()
+        versions = np.concatenate([np.full(len(c.pool_idx), c.version)
+                                   for c in calls])
+        pairs = np.stack([rows, versions], axis=1)
+        uniq, inv = np.unique(pairs, axis=0, return_inverse=True)
+        inv = inv.reshape(-1)
+        ref_ids = reference.exact_topk_live(
+            *books, pool[uniq[:, 0]], uniq[:, 1], k)[0][inv]
+        found, failed = checks.judge_live(
+            books, pool[rows], versions, out["ids"], out["dists"], ref_ids,
+            back, live.version, len(live.bad_acks), cfg["guarantees"])
+        attempted += sum(len(v) for v, _ in back.values()) + live.attempted
+        for msg in live.bad_acks[:20]:
+            say(f"bad acknowledgement: {msg}")
+    else:
+        uniq, inv = np.unique(rows, return_inverse=True)
+        ref_ids = reference.exact_topk(base, pool[uniq], k)[0][inv]
+        found, failed = checks.judge(base, pool[rows], out["ids"],
+                                     out["dists"], ref_ids,
+                                     cfg["guarantees"])
     correct = all(c["holds"] for c in found.values())
 
     ctx = Ctx(config=cfg, mix=mix, calls=calls, setup_s=setup_s,
               build_s=build_s, warmup_s=warmup_s,
               recall=found["recall_at_10"]["value"], page_bytes=page_bytes,
-              pq_m=pq_m, red=red, peaks=peaks)
+              pq_m=pq_m, red=red, peaks=peaks, steps=steps)
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = {}
     for m in registry.metrics(bench, args.workload, kind):
@@ -259,7 +374,7 @@ def main(argv=None) -> int:
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": peak_bytes}
-    result = {"correct": correct, "attempted": int(len(rows)),
+    result = {"correct": correct, "attempted": int(attempted),
               "failed": failed, "metrics": metrics, "device": device}
     if red is not None:
         from bench.trace_reduce import breakdown
