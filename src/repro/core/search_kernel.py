@@ -278,7 +278,10 @@ def _search_batch(page_vids, page_vecs, page_nbrs, vid2page, vid2slot,
 
         # final top-k by exact distance (re-rank among exact-known)
         with jax.named_scope("rerank"):
-            final_key = jnp.where(flags[:, 1], keys[:, 1], INF)
+            # a padding row (SENTINEL) can carry the flags and exact key of
+            # a duplicate it was merged from: it never ranks
+            final_key = jnp.where(flags[:, 1] & (ids < SENTINEL), keys[:, 1],
+                                  INF)
             order = jnp.argsort(final_key)[:k]
             topk = jnp.where(final_key[order] < INF, ids[order], -1)
             topd = final_key[order]

@@ -106,6 +106,20 @@ def beam_search_mem(X, G, entry: int, q, L=64, width=1, max_iters=None,
         L=L, width=width, max_iters=max_iters, visited_cap=visited_cap)
 
 
+@functools.partial(jax.jit, static_argnames=("L", "width"))
+def _insert_candidates(X, G, entry, q, *, L, width):
+    """An insert's candidate search (streaming flush): `beam_search_mem`
+    from the one entry `entry` (an int32 scalar), compiled as a program of
+    its own name so a device trace tells insert wiring from MemGraph
+    navigation. Returns (visited ids (B, V), top-L ids (B, L))."""
+    B = q.shape[0]
+    max_iters = 4 * L
+    res = _beam_search_mem_batch(
+        X, G, jnp.full((B, 1), entry, jnp.int32), jnp.ones((B, 1), bool), q,
+        L=L, width=width, max_iters=max_iters, visited_cap=width * max_iters)
+    return res["visited_ids"], res["ids"]
+
+
 # ---------------------------------------------------------------------------
 # RobustPrune
 

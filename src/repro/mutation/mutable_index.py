@@ -72,6 +72,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Set
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pq as pq_mod
@@ -81,10 +82,11 @@ from repro.core.page_shuffle import bfs_order, greedy_pack, \
 from repro.core.pages import PageLayout, overlap_ratio
 from repro.core.search_kernel import search_batched
 from repro.core.stats import QueryStats
-from repro.core.vamana import beam_search_mem
+from repro.core.vamana import _insert_candidates
 from repro.io import build_store
 from repro.mutation.delta_index import DeltaIndex
 from repro.mutation.journal import CrashPoint, MutationJournal
+from repro.obs import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +97,12 @@ class MutationConfig:
     growth_chunk: int = 256      # vid-capacity growth quantum: arrays (and
     #                              page space) grow in chunks so the jitted
     #                              kernel recompiles per CHUNK, not per flush
+    capacity: int = 0            # vid rows reserved up front, the base
+    #                              included (0: none): every array, and the
+    #                              pages maybe_flush() fills for them, take
+    #                              their final shape at construction, so
+    #                              serving and flushing compile nothing new
+    #                              until the stream outgrows the reserve
     insert_L: int = 32           # beam width of the insert candidate search
     insert_width: int = 2
     insert_alpha: float = 1.2    # robust-prune slack for insert edges
@@ -109,6 +117,8 @@ class MutationConfig:
         if self.growth_chunk < 1:
             raise ValueError(
                 f"growth_chunk={self.growth_chunk} must be >= 1")
+        if self.capacity < 0:
+            raise ValueError(f"capacity={self.capacity} must be >= 0")
         if self.insert_L < 1 or self.insert_width < 1:
             raise ValueError("insert_L and insert_width must be >= 1")
         if self.insert_alpha < 1.0:
@@ -151,6 +161,7 @@ class MutableIndex:
         self.pq = pq_mod.PQ(centroids=base.pq.centroids,
                             codes=base.pq.codes.copy(),
                             m=base.pq.m, dsub=base.pq.dsub)
+        self._encode = pq_mod.encoder(base.pq.centroids)   # flush's codes
         self.medoid = base.medoid
         self.memgraph = base.memgraph
         self.cached = base.cached.copy()
@@ -181,6 +192,13 @@ class MutableIndex:
             self._rev[v].add(int(u))
         self.flushes = 0
         self.compactions = 0
+        self.rows_flushed = 0        # rows flush() has placed on pages
+        self.capacity_growths = 0    # array or page-space growths past the
+        #                              reserve (each one a new kernel shape)
+        self.pq_upload_bytes = 0     # PQ codes sent to the device after a
+        #                              flush changed them
+        self.flush_upload_bytes = 0  # vectors + graph sent to the device
+        #                              for flush()'s candidate search
         self._mutated = False
         self._stores: List = []      # attached MutablePageStores
         self._facade_stores: Dict[bool, object] = {}
@@ -194,6 +212,29 @@ class MutableIndex:
         #                              (consumed/reported by serve_open_loop)
         self._recovered_rng_state: Optional[dict] = None  # journaled cursor
         self._replaying = False      # recovery replay must not re-journal
+        if self.mcfg.capacity > n:
+            self._reserve(self.mcfg.capacity)
+
+    def _reserve(self, capacity: int) -> None:
+        """Give every per-vid array `capacity` rows and the page space room
+        for the rows maybe_flush() will place (each flush starts on wholly
+        empty pages, so each can leave one page partly empty; one flush's
+        pages more for a last partial flush), then compile flush()'s two
+        device programs at the shapes it will run them with. A reserved vid
+        stays deleted (filtered) until a flush fills its row."""
+        rows = capacity - self.capacity
+        f, n_p = self.mcfg.flush_threshold, self.layout.n_p
+        per_flush = -(-f // n_p)
+        self._grow_rows(rows)
+        self.free_pages.extend(self._grow_pages(
+            (-(-rows // f) + 1) * per_flush))
+        X, G = jnp.asarray(self.vectors), jnp.asarray(self.graph)
+        q = np.zeros((f, self.d), np.float32)
+        q = jnp.asarray(q)
+        _insert_candidates(X, G, jnp.int32(self.medoid), q,
+                           L=self.mcfg.insert_L,
+                           width=self.mcfg.insert_width)[0].block_until_ready()
+        self._encode(q).block_until_ready()
 
     # -- DiskIndex-compatible surface ---------------------------------------
 
@@ -202,16 +243,15 @@ class MutableIndex:
         return self.graph.shape[0]
 
     @property
-    def mutated(self) -> bool:
-        return self._mutated
-
-    @property
     def live_count(self) -> int:
         return int((~self.deleted[:self.n_disk]).sum()) + len(self.delta)
 
     @property
     def dirty_fraction(self) -> float:
-        return len(self.dirty_pages) / max(self.layout.num_pages, 1)
+        """Dirty pages over the pages holding records (free pages, the
+        reserve among them, are not part of the index)."""
+        in_use = self.layout.num_pages - len(self.free_pages)
+        return len(self.dirty_pages) / max(in_use, 1)
 
     def overlap_ratio(self) -> float:
         """OR(G) over LIVE vertices only — the locality signal whose decay
@@ -219,11 +259,22 @@ class MutableIndex:
         return overlap_ratio(self.layout, self.graph, alive=~self.deleted)
 
     def mutation_stats(self) -> dict:
+        """State and counters. `reupload_bytes`: bytes sent to the device
+        again because a mutation made the device copy stale (the layout's
+        kernel arrays in every attached store after an invalidation, the
+        PQ codes after a flush); `flush_upload_bytes`: the vectors and
+        graph each flush's candidate search reads."""
         return {"n_disk": self.n_disk, "delta_size": len(self.delta),
                 "pending_tombstones": len(self.pending_tombstones),
                 "dirty_pages": len(self.dirty_pages),
                 "free_pages": len(self.free_pages),
                 "flushes": self.flushes, "compactions": self.compactions,
+                "rows_flushed": self.rows_flushed,
+                "capacity": self.capacity,
+                "capacity_growths": self.capacity_growths,
+                "reupload_bytes": self.pq_upload_bytes + sum(
+                    getattr(st, "reupload_bytes", 0) for st in self._stores),
+                "flush_upload_bytes": self.flush_upload_bytes,
                 "live": self.live_count,
                 "overlap_ratio": round(self.overlap_ratio(), 4)}
 
@@ -300,30 +351,32 @@ class MutableIndex:
     def insert(self, vec: np.ndarray) -> int:
         """Stage a vector in the delta; it becomes disk-resident at the
         next flush. Returns the assigned vid."""
-        vec = np.asarray(vec, np.float32).reshape(-1)
-        self._journal_append("insert", vec)
-        self.ops_applied += 1
-        vid = self.next_vid
-        self.next_vid += 1
-        self.delta.insert(vid, vec)
-        self._mutated = True
-        return vid
+        with span("ann.mutate.insert"):
+            vec = np.asarray(vec, np.float32).reshape(-1)
+            self._journal_append("insert", vec)
+            self.ops_applied += 1
+            vid = self.next_vid
+            self.next_vid += 1
+            self.delta.insert(vid, vec)
+            self._mutated = True
+            return vid
 
     def delete(self, vid: int) -> bool:
         """Tombstone a vid. Delta vids die in memory; disk vids keep their
         record (routing) until compaction purges the page."""
-        vid = int(vid)
-        self._journal_append("delete", vid)
-        self.ops_applied += 1
-        self._mutated = True
-        if vid in self.delta:
-            return self.delta.remove(vid)
-        if vid < 0 or vid >= self.n_disk or self.deleted[vid]:
-            return False
-        self.deleted[vid] = True
-        self.pending_tombstones.add(vid)
-        self.dirty_pages.add(int(self.layout.vid2page[vid]))
-        return True
+        with span("ann.mutate.delete"):
+            vid = int(vid)
+            self._journal_append("delete", vid)
+            self.ops_applied += 1
+            self._mutated = True
+            if vid in self.delta:
+                return self.delta.remove(vid)
+            if vid < 0 or vid >= self.n_disk or self.deleted[vid]:
+                return False
+            self.deleted[vid] = True
+            self.pending_tombstones.add(vid)
+            self.dirty_pages.add(int(self.layout.vid2page[vid]))
+            return True
 
     def random_live_vid(self, rng: np.random.Generator) -> Optional[int]:
         """A uniformly random live DISK vid (delete-workload driver).
@@ -353,13 +406,10 @@ class MutableIndex:
 
     # -- capacity growth (chunked: bounds kernel recompiles) -----------------
 
-    def _ensure_vid_capacity(self, n: int) -> None:
-        cap = self.capacity
-        if n <= cap:
-            return
-        chunk = self.mcfg.growth_chunk
-        new_cap = ((n + chunk - 1) // chunk) * chunk
-        grow = new_cap - cap
+    def _grow_rows(self, grow: int) -> None:
+        """`grow` more vid rows in every per-vid array: deleted, edgeless,
+        mapped to page 0 slot 0 (never referenced: no edge reaches a vid
+        that was never flushed)."""
         self.vectors = np.concatenate(
             [self.vectors, np.zeros((grow, self.d), np.float32)])
         self.graph = np.concatenate(
@@ -372,36 +422,45 @@ class MutableIndex:
         self.cached = np.concatenate([self.cached, np.zeros(grow, bool)])
         self._rev.extend(set() for _ in range(grow))
         lay = self.layout
-        # unassigned vids map to page 0 slot 0 — never referenced (no edge
-        # reaches a vid that was never flushed)
         lay.vid2page = np.concatenate(
             [lay.vid2page, np.zeros(grow, lay.vid2page.dtype)])
         lay.vid2slot = np.concatenate(
             [lay.vid2slot, np.zeros(grow, lay.vid2slot.dtype)])
 
+    def _grow_pages(self, grow: int) -> range:
+        """Append `grow` wholly-empty pages to the layout; returns their
+        ids."""
+        lay = self.layout
+        P = lay.num_pages
+        lay.page_vids = np.concatenate(
+            [lay.page_vids, np.full((grow, lay.n_p), -1, lay.page_vids.dtype)])
+        lay.page_vecs = np.concatenate(
+            [lay.page_vecs, np.zeros((grow,) + lay.page_vecs.shape[1:],
+                                     lay.page_vecs.dtype)])
+        lay.page_nbrs = np.concatenate(
+            [lay.page_nbrs, np.full((grow,) + lay.page_nbrs.shape[1:], -1,
+                                    lay.page_nbrs.dtype)])
+        lay.num_pages = P + grow
+        return range(P, P + grow)
+
+    def _ensure_vid_capacity(self, n: int) -> None:
+        cap = self.capacity
+        if n <= cap:
+            return
+        chunk = self.mcfg.growth_chunk
+        self._grow_rows(((n + chunk - 1) // chunk) * chunk - cap)
+        self.capacity_growths += 1
+
     def _ensure_free_pages(self, pages_needed: int) -> List[int]:
         """Allocate `pages_needed` wholly-empty pages, appending a CHUNK of
         empty pages to the layout when the free list runs short (shape
         growth is the expensive event — amortize it)."""
-        lay = self.layout
         if len(self.free_pages) < pages_needed:
-            chunk = max(1, self.mcfg.growth_chunk // lay.n_p)
+            chunk = max(1, self.mcfg.growth_chunk // self.layout.n_p)
             short = pages_needed - len(self.free_pages)
-            grow = ((short + chunk - 1) // chunk) * chunk
-            P = lay.num_pages
-            lay.page_vids = np.concatenate(
-                [lay.page_vids,
-                 np.full((grow, lay.n_p), -1, lay.page_vids.dtype)])
-            lay.page_vecs = np.concatenate(
-                [lay.page_vecs,
-                 np.zeros((grow,) + lay.page_vecs.shape[1:],
-                          lay.page_vecs.dtype)])
-            lay.page_nbrs = np.concatenate(
-                [lay.page_nbrs,
-                 np.full((grow,) + lay.page_nbrs.shape[1:], -1,
-                         lay.page_nbrs.dtype)])
-            lay.num_pages = P + grow
-            self.free_pages.extend(range(P, P + grow))
+            self.free_pages.extend(
+                self._grow_pages(((short + chunk - 1) // chunk) * chunk))
+            self.capacity_growths += 1
         taken = self.free_pages[:pages_needed]
         del self.free_pages[:pages_needed]
         return taken
@@ -496,22 +555,55 @@ class MutableIndex:
         every touched page. Returns the I/O accounting dict the serving
         layer prices: {flushed, pages_read, pages_written, read_pages,
         written_pages}."""
-        # two-phase: the flush intent is durable BEFORE any page moves —
-        # recovery re-runs the whole flush from the journaled inserts
-        self._journal_append("flush", None, sync=True)
-        self.ops_applied += 1
-        vids, vecs = self.delta.drain()
+        with span("ann.mutate.flush"):
+            # two-phase: the flush intent is durable BEFORE any page moves
+            # — recovery re-runs the whole flush from the journaled inserts
+            self._journal_append("flush", None, sync=True)
+            self.ops_applied += 1
+            vids, vecs = self.delta.drain()
+            if len(vids) == 0:
+                return {"flushed": 0, "pages_read": 0, "pages_written": 0,
+                        "read_pages": np.zeros(0, np.int64),
+                        "written_pages": np.zeros(0, np.int64)}
+            return self._place(vids, vecs)
+
+    def _insert_device_work(self, vecs: np.ndarray):
+        """PQ codes and graph-search candidates of the rows being flushed,
+        on the device, in batches of flush_threshold rows (the last padded
+        with copies of the first row), so each program keeps one shape.
+        Returns (codes (m, M), visited ids (m, V), top-L ids (m, L))."""
+        m, f = len(vecs), self.mcfg.flush_threshold
+        q = np.concatenate([vecs, np.repeat(vecs[:1], -m % f, axis=0)])
+        blocks = [jnp.asarray(q[s:s + f]) for s in range(0, len(q), f)]
+        codes = np.concatenate([np.asarray(self._encode(b)) for b in blocks])
+        X, G = jnp.asarray(self.vectors), jnp.asarray(self.graph)
+        self.flush_upload_bytes += self.vectors.nbytes + self.graph.nbytes
+        entry = jnp.int32(self.medoid)
+        vis, top = [], []
+        with span("ann.mutate.flush.candidates"):
+            for b in blocks:
+                v, t = _insert_candidates(X, G, entry, b,
+                                          L=self.mcfg.insert_L,
+                                          width=self.mcfg.insert_width)
+                vis.append(np.asarray(v))
+                top.append(np.asarray(t))
+        return (codes[:m], np.concatenate(vis)[:m],
+                np.concatenate(top)[:m])
+
+    def _place(self, vids: np.ndarray, vecs: np.ndarray) -> dict:
+        """flush()'s work on the drained rows: pages, graph edges, codes."""
         m = len(vids)
-        if m == 0:
-            return {"flushed": 0, "pages_read": 0, "pages_written": 0,
-                    "read_pages": np.zeros(0, np.int64),
-                    "written_pages": np.zeros(0, np.int64)}
         lay = self.layout
         self._ensure_vid_capacity(self.next_vid)
         self.vectors[vids] = vecs
         self.deleted[vids] = False
-        self.pq.codes[vids] = pq_mod.encode(vecs, self.pq.centroids)
-        self.pq.__dict__.pop("_device_arrays", None)
+        codes, vis, top = self._insert_device_work(vecs)
+        self.pq.codes[vids] = codes
+        dev = self.pq.__dict__.get("_device_arrays")
+        self.pq._device_arrays = (
+            dev[0] if dev is not None else jnp.asarray(self.pq.centroids),
+            jnp.asarray(self.pq.codes))
+        self.pq_upload_bytes += self.pq.codes.nbytes
 
         # --- place in arrival order onto wholly-empty pages ----------------
         n_p = lay.n_p
@@ -523,31 +615,28 @@ class MutableIndex:
             lay.vid2slot[vid] = s
         self.n_disk = self.next_vid
 
-        # --- graph wiring: beam-search candidates + robust prune -----------
-        mcfg = self.mcfg
-        res = beam_search_mem(self.vectors, self.graph, self.medoid, vecs,
-                              L=mcfg.insert_L, width=mcfg.insert_width)
-        vis = np.asarray(res["visited_ids"])
-        top = np.asarray(res["ids"])
+        # --- graph wiring: robust prune of the candidates + back-edges -----
         modified: Set[int] = set()
-        # two passes: every new row is FINAL before any back-edge lands in
-        # it — a one-pass interleave would wipe back-edges already placed
-        # into a later batch-mate's row (and desync the reverse index)
-        for i, vid in enumerate(vids):
-            cand = np.concatenate([vis[i], top[i], vids])
-            cand = np.unique(cand[(cand >= 0) & (cand < self.n_disk)])
-            cand = cand[(cand != vid) & ~self.deleted[cand]]
-            if len(cand) == 0:
-                cand = np.asarray([self.medoid], np.int64)
-            nbrs = self._robust_prune(vecs[i], cand)
-            self.graph[vid] = -1
-            self.graph[vid, :len(nbrs)] = nbrs
-            for u in nbrs:
-                self._rev[int(u)].add(int(vid))
-        for vid in vids:
-            for u in self.graph[vid]:
-                if u >= 0 and self._add_back_edge(int(u), int(vid)):
-                    modified.add(int(u))
+        with span("ann.mutate.flush.wire"):
+            # two passes: every new row is FINAL before any back-edge lands
+            # in it — a one-pass interleave would wipe back-edges already
+            # placed into a later batch-mate's row (and desync the reverse
+            # index)
+            for i, vid in enumerate(vids):
+                cand = np.concatenate([vis[i], top[i], vids])
+                cand = np.unique(cand[(cand >= 0) & (cand < self.n_disk)])
+                cand = cand[(cand != vid) & ~self.deleted[cand]]
+                if len(cand) == 0:
+                    cand = np.asarray([self.medoid], np.int64)
+                nbrs = self._robust_prune(vecs[i], cand)
+                self.graph[vid] = -1
+                self.graph[vid, :len(nbrs)] = nbrs
+                for u in nbrs:
+                    self._rev[int(u)].add(int(vid))
+            for vid in vids:
+                for u in self.graph[vid]:
+                    if u >= 0 and self._add_back_edge(int(u), int(vid)):
+                        modified.add(int(u))
 
         # --- rewrite + account ---------------------------------------------
         # back-edge pages are read-modify-written and invalidated, but NOT
@@ -565,6 +654,7 @@ class MutableIndex:
         self.dirty_pages.update(int(p) for p in pages)
         self.append_pages.update(int(p) for p in pages)
         self.flushes += 1
+        self.rows_flushed += m
         self._crash_ticks(len(written))   # each data-page write can kill
         self._notify_growth()
         self._charge_background(read, written)
@@ -607,14 +697,20 @@ class MutableIndex:
         budget = max_pages or self.mcfg.compaction_pages
         if budget < 1:
             raise ValueError(f"max_pages={budget} must be >= 1")
-        # journal the RESOLVED budget: replay must compact the same slice
-        self._journal_append("compact", int(budget), sync=True)
-        self.ops_applied += 1
-        if not self.dirty_pages:
-            return {"compacted_pages": 0, "purged": 0, "relocated": 0,
-                    "repacked": 0, "pages_read": 0, "pages_written": 0,
-                    "read_pages": np.zeros(0, np.int64),
-                    "written_pages": np.zeros(0, np.int64)}
+        with span("ann.mutate.compact"):
+            # journal the RESOLVED budget: replay must compact the same
+            # slice
+            self._journal_append("compact", int(budget), sync=True)
+            self.ops_applied += 1
+            if not self.dirty_pages:
+                return {"compacted_pages": 0, "purged": 0, "relocated": 0,
+                        "repacked": 0, "pages_read": 0, "pages_written": 0,
+                        "read_pages": np.zeros(0, np.int64),
+                        "written_pages": np.zeros(0, np.int64)}
+            return self._compact_pages(budget)
+
+    def _compact_pages(self, budget: int) -> dict:
+        """compact()'s three steps over up to `budget` dirty pages."""
         self._mutated = True
         lay = self.layout
         pages = sorted(self.dirty_pages)[:budget]
@@ -828,11 +924,16 @@ class MutableIndex:
     # -- search (the merged path) -------------------------------------------
 
     def disk_cfg(self, cfg: Optional[SearchConfig] = None) -> SearchConfig:
-        """The SearchConfig the DISK side of a merged search runs: while
-        tombstones are pending, the kernel overfetches so filtered slots
-        can backfill from the candidate pool."""
+        """The SearchConfig the DISK side of a merged search runs: the
+        kernel overfetches `overfetch` more results (at most L), so slots
+        the tombstone filter empties backfill from the candidate pool. It
+        does so whether or not a tombstone is pending, so a served index
+        runs one kernel shape from its first call on: the kernel's final
+        top-k is a stable sort of its candidate pool, so the first k of
+        k + overfetch are the top-k it returns at k, and merge_mutations
+        truncates back to k."""
         cfg = cfg or self.cfg
-        if not self.pending_tombstones or self.mcfg.overfetch == 0:
+        if self.mcfg.overfetch == 0:
             return cfg
         return cfg.replace(k=min(cfg.L, cfg.k + self.mcfg.overfetch))
 

@@ -60,6 +60,9 @@ class MutablePageStore:
         self.counters = StoreCounters()
         self.page_version = np.zeros(inner.num_pages, np.int64)
         self.invalidations = 0      # stale cached copies actually evicted
+        self.reupload_bytes = 0     # kernel arrays sent to the device again
+        #                             after a rewrite made them stale
+        self._stale = False         # memos dropped since the last upload
         # durability hooks (repro/mutation/journal.py): a store-owned
         # journal makes every data-page write two-phase (synced intent
         # record first); a CrashPoint numbers + kills the I/O boundaries
@@ -96,7 +99,11 @@ class MutablePageStore:
     _MIRRORED_METHODS = ("replay_batch", "coalesce", "fetch_for_queries")
 
     def kernel_arrays(self) -> tuple:
-        return self.inner.kernel_arrays()
+        arrays = self.inner.kernel_arrays()
+        if self._stale:              # the inner store just uploaded anew
+            self._stale = False
+            self.reupload_bytes += sum(int(a.nbytes) for a in arrays)
+        return arrays
 
     def vertex_cache_mask(self) -> np.ndarray:
         return self.inner.vertex_cache_mask()
@@ -135,6 +142,7 @@ class MutablePageStore:
         memoized on THIS object (`_device_cache_mask`, stamped by
         search_batched). A rewrite makes both stale."""
         self.__dict__.pop("_device_cache_mask", None)
+        self._stale = True
         for layer in self._layers():
             layer.__dict__.pop("_device_cache_mask", None)
             if hasattr(layer, "_kernel_cache"):

@@ -571,10 +571,12 @@ class AnnServer:
         `collect=True` forces that trace on any store so a Tracer can emit
         per-hop device spans (one extra jit entry while tracing).
 
-        Over a MutableIndex with pending mutations the disk side runs the
-        tombstone-overfetch config and the delta's exact results are merged
-        into the result heap (MutableIndex.merge_mutations) — with zero
-        mutations both are identity and the frozen path is bit-identical."""
+        Over a MutableIndex the disk side always runs the tombstone-
+        overfetch config (MutableIndex.disk_cfg: one kernel shape from the
+        first call on, mutated or not) and the delta's exact results are
+        merged into the result heap (MutableIndex.merge_mutations, span
+        `ann.search.merge`) — with zero mutations the merged ids and
+        distances are the frozen path's."""
         cfg = cfg or self.cfg
         orig = qvecs
         b = len(qvecs)
@@ -582,16 +584,16 @@ class AnnServer:
         if self.server_cfg.pad_batches and b < mb:
             qvecs = np.concatenate(
                 [qvecs, np.repeat(qvecs[:1], mb - b, axis=0)])
-        kcfg = (self.index.disk_cfg(cfg)
-                if self._mutable and self.index.mutated else cfg)
+        kcfg = self.index.disk_cfg(cfg) if self._mutable else cfg
         stats = search_batched(
             self.store, self.index.pq, kcfg, qvecs,
             medoid=self.index.medoid, memgraph=self.index.memgraph,
             batch=len(qvecs), collect_trace=self._stateful or collect,
             account_kernel_io=False)
         stats = stats.take(b)
-        if self._mutable and self.index.mutated:
-            stats = self.index.merge_mutations(stats, orig, cfg)
+        if self._mutable:
+            with span("ann.search.merge"):
+                stats = self.index.merge_mutations(stats, orig, cfg)
         return stats
 
     def _level_cfg(self, level: int):

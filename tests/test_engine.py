@@ -123,3 +123,19 @@ def test_device_model_io_bound(base_index, small_dataset):
                   page_bytes=cfg.page_bytes)
     assert 0.5 < s["io_fraction"] <= 1.0   # I/O dominates (paper Fig. 2)
     assert s["qps"] > 0 and s["mean_latency_us"] > 0
+
+
+@pytest.mark.parametrize("max_iters", [1, 3])
+def test_padding_ids_never_rank(base_index, small_dataset, max_iters):
+    """A search stopped after few hops knows fewer exact distances than k:
+    the rest of its answer is -1 at +inf, never a padding id carrying a
+    merged duplicate's distance."""
+    cfg = base_index.cfg.replace(k=24, L=24, max_iters=max_iters)
+    res = base_index.search(small_dataset.queries[:32], cfg)
+    ids, n = np.asarray(res.ids), small_dataset.n
+    assert ((ids == -1) | ((ids >= 0) & (ids < n))).all()
+    assert (ids == -1).any()
+    assert (np.asarray(res.dists)[ids == -1] >= 3e38).all()   # INF
+    for row in ids:
+        real = row[row >= 0]
+        assert len(np.unique(real)) == len(real)

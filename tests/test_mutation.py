@@ -518,3 +518,172 @@ def test_overlap_decays_without_compaction_and_recovers_with_it(
     assert ors_comp[-1] > ors_none[-1]
     assert len(mi_comp.pending_tombstones) < len(mi_none.pending_tombstones)
     assert len(mi_comp.dirty_pages) < len(mi_none.dirty_pages)
+
+
+# --------------------------------------------------------------------------
+# default tier: a reserved capacity under a seeded churn stream through
+# AnnServer — the calls bench/run.py's make_mutate makes
+
+
+class _CompileCount:
+    """Backend compiles in this process, from jax.monitoring (the event the
+    benchmark's window line counts)."""
+
+    count = 0
+    _on = False
+
+    @classmethod
+    def start(cls):
+        if not cls._on:
+            import jax
+
+            def hear(event, _secs, **_kw):
+                if event == "/jax/core/compile/backend_compile_duration":
+                    cls.count += 1
+            jax.monitoring.register_event_duration_secs_listener(hear)
+            cls._on = True
+        return cls.count
+
+
+def _live_topk(vecs, live_vids, q, k):
+    """Plain numpy brute force: the k nearest live vids of each query."""
+    X = vecs[live_vids].astype(np.float64)
+    d = np.square(q[:, None, :].astype(np.float64) - X[None]).sum(-1)
+    return live_vids[np.argsort(d, axis=1, kind="stable")[:, :k]]
+
+
+def _churn(base_index, small_dataset, *, capacity, steps, seed=17):
+    """A seeded stream: 2 served batches of 8, then 16 inserts (held-out
+    midpoints of base rows) and 16 uniform deletes of live vids, each
+    insert followed by maybe_flush and the compactor's after_mutation, each
+    delete by after_mutation, each batch by after_batch. Returns the
+    server, the compiles after its warm call, every answer with the live
+    set it was served over, and the books."""
+    from repro.serving import AnnServer, ServerConfig
+    n, k = small_dataset.n, base_index.cfg.k
+    mi = MutableIndex(base_index, MutationConfig(
+        flush_threshold=16, insert_L=16, capacity=capacity))
+    srv = AnnServer(mi, server_cfg=ServerConfig(max_batch=8))
+    comp = Compactor(mi, MutationMix(compaction="threshold", threshold=0.02,
+                                     max_pages=8))
+    rng = np.random.default_rng(seed)
+    vecs = np.zeros((n + 16 * steps, small_dataset.d), np.float32)
+    vecs[:n] = small_dataset.vectors
+    live = list(range(n))
+    queries = small_dataset.queries
+    srv.serve_closed_loop(queries[:8], workers=8)          # the warm call
+    before = _CompileCount.start()
+    served = []
+    for step in range(steps):
+        for _ in range(2):
+            q = queries[rng.choice(len(queries), 8, replace=False)]
+            rep = srv.serve_closed_loop(q, workers=8)
+            inv = np.argsort(rep.query_indices, kind="stable")
+            served.append((q, rep.stats.ids[inv], np.asarray(live)))
+            comp.after_batch()
+        for _ in range(16):
+            a, b = rng.integers(0, n, 2)
+            vec = 0.5 * (small_dataset.vectors[a] + small_dataset.vectors[b])
+            vid = mi.insert(vec)
+            vecs[vid] = vec
+            live.append(vid)
+            mi.maybe_flush()
+            comp.after_mutation()
+        for _ in range(16):
+            vid = live.pop(int(rng.integers(len(live))))
+            assert mi.delete(vid)
+            comp.after_mutation()
+    compiles = _CompileCount.start() - before
+    return srv, compiles, served, vecs, np.asarray(live)
+
+
+def _check_against_brute_force(mi, served, vecs, live, floor):
+    k = mi.cfg.k
+    hits = total = 0
+    for q, ids, live_then in served:
+        ref = _live_topk(vecs, live_then, q, k)
+        assert np.isin(ids[ids >= 0], live_then).all(), "dead id served"
+        hits += sum(len(np.intersect1d(a, b)) for a, b in zip(ids, ref))
+        total += ref.size
+    assert hits / total >= floor, hits / total
+    # every inserted row still live finds its own vid
+    fresh = live[live >= len(mi.base.layout.vid2page)]
+    got = mi.search(vecs[fresh]).ids
+    assert (got == fresh[:, None]).any(axis=1).all()
+
+
+@pytest.fixture(scope="module")
+def churned(base_index, small_dataset):
+    return _churn(base_index, small_dataset,
+                  capacity=small_dataset.n + 16 * 12, steps=12)
+
+
+def test_churn_stream_matches_brute_force_over_the_live_set(churned):
+    """Recall@10 of every served answer against a numpy brute force over
+    the vids live when it was served (floor 0.90), no dead id, and every
+    live insert finds itself; the reserve held."""
+    srv, _, served, vecs, live = churned
+    mi = srv.index
+    st = mi.mutation_stats()
+    assert st["flushes"] >= 10 and st["compactions"] >= 1
+    assert st["capacity_growths"] == 0
+    assert st["rows_flushed"] == 16 * st["flushes"]
+    assert st["live"] == len(live)
+    _check_against_brute_force(mi, served, vecs, live, floor=0.90)
+
+
+def test_churn_stream_compiles_nothing_after_the_warm_call(churned):
+    """Construction (MutableIndex compiles flush's two programs at the
+    reserved shapes) and one served call (the over-fetch kernel shape)
+    warm everything the stream runs: no compile after them."""
+    srv, compiles, _, _, _ = churned
+    assert compiles == 0
+    st = srv.index.mutation_stats()
+    # counters of what the device was sent again
+    per_flush = srv.index.vectors.nbytes + srv.index.graph.nbytes
+    assert st["flush_upload_bytes"] == st["flushes"] * per_flush
+    assert st["reupload_bytes"] > 0
+
+
+def test_churn_past_the_reserve_grows_and_stays_correct(base_index,
+                                                        small_dataset):
+    """A stream that outgrows `capacity` grows the arrays (and recompiles,
+    counted by capacity_growths) and still answers over the live set."""
+    srv, _, served, vecs, live = _churn(
+        base_index, small_dataset, capacity=small_dataset.n + 16, steps=4,
+        seed=5)
+    mi = srv.index
+    assert mi.mutation_stats()["capacity_growths"] >= 1
+    assert mi.capacity > small_dataset.n + 16
+    _check_against_brute_force(mi, served, vecs, live, floor=0.90)
+
+
+def test_reserve_and_overfetch_change_nothing_unmutated(base_index,
+                                                        small_dataset):
+    """A never-mutated MutableIndex with a reserve, served through AnnServer
+    (whose disk side always runs k + overfetch), returns the frozen
+    path's ids, distances and counters; so does its facade."""
+    from repro.serving import AnnServer, ServerConfig
+    mi = MutableIndex(base_index, MutationConfig(
+        flush_threshold=16, insert_L=16, capacity=small_dataset.n + 500))
+    assert mi.capacity == small_dataset.n + 500
+    assert mi.layout.num_pages > base_index.layout.num_pages
+    assert mi.disk_cfg().k == mi.cfg.k + mi.mcfg.overfetch
+    q = small_dataset.queries[:16]
+    scfg = ServerConfig(max_batch=8)
+    a = AnnServer(base_index, server_cfg=scfg).serve_closed_loop(q, 8, 2)
+    b = AnnServer(mi, server_cfg=scfg).serve_closed_loop(q, 8, 2)
+    for f in ("ids", "dists", "page_reads", "hops", "pq_evals",
+              "full_evals"):
+        assert np.array_equal(getattr(a.stats, f), getattr(b.stats, f)), f
+    assert np.array_equal(a.query_indices, b.query_indices)
+    fa, fb = base_index.search(q), mi.search(q)
+    assert np.array_equal(fa.ids, fb.ids)
+    assert np.array_equal(fa.dists, fb.dists)
+    assert mi.mutation_stats()["capacity_growths"] == 0
+
+
+@pytest.mark.fast
+def test_mutation_config_rejects_negative_capacity():
+    with pytest.raises(ValueError, match="capacity"):
+        MutationConfig(capacity=-1)

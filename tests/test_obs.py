@@ -276,3 +276,34 @@ def test_search_batched_bit_identical_with_and_without_profiler(
               "full_evals", "visited_pages"):
         a, b = getattr(off, f), getattr(on, f)
         assert a.dtype == b.dtype and np.array_equal(a, b), f
+
+
+def test_mutation_spans_land_in_the_profile(tmp_path, base_index,
+                                            small_dataset):
+    """The mutation path's spans: one per insert, delete, flush (with its
+    candidate search and wiring nested in it) and compaction run, and
+    `ann.search.merge` per batch served over a MutableIndex."""
+    from repro.mutation import MutableIndex, MutationConfig
+    mi = MutableIndex(base_index, MutationConfig(flush_threshold=4,
+                                                 insert_L=16))
+    srv = AnnServer(mi, get_preset("baseline", L=16))
+    q = small_dataset.queries[:8]
+    srv.serve_closed_loop(q, workers=8)             # compile outside
+
+    def work():
+        for v in small_dataset.vectors[:4]:
+            mi.insert(v)
+        mi.flush()
+        mi.delete(0)
+        mi.compact(max_pages=2)
+        srv.serve_closed_loop(q, workers=8)
+    _, events = _profiled(tmp_path, work)
+    counts = {n: len(events.get(n, ())) for n in (
+        "ann.mutate.insert", "ann.mutate.delete", "ann.mutate.flush",
+        "ann.mutate.flush.candidates", "ann.mutate.flush.wire",
+        "ann.mutate.compact", "ann.search.merge")}
+    assert counts == {"ann.mutate.insert": 4, "ann.mutate.delete": 1,
+                      "ann.mutate.flush": 1,
+                      "ann.mutate.flush.candidates": 1,
+                      "ann.mutate.flush.wire": 1, "ann.mutate.compact": 1,
+                      "ann.search.merge": 1}

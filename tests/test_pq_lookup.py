@@ -1,10 +1,12 @@
 """The ADC table lookup of the beam search: the TPU's select form against
-the CPU's gather form, called directly at the served widths."""
+the CPU's gather form, called directly at the served widths; and PQ
+encoding, which a streaming flush calls once per flush."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.pq import encode, encoder
 from repro.core.search_kernel import adc_lookup_gather, adc_lookup_select
 from repro.core.searchutils import SENTINEL
 
@@ -36,3 +38,24 @@ def test_select_lookup_matches_gather(n):
     assert float(jnp.min(gat)) == 0.0
     np.testing.assert_allclose(jnp.sum(sel, -1), jnp.sum(gat, -1),
                                rtol=1e-6)
+
+
+def test_encode_is_the_nearest_centroid_and_compiles_once_per_shape():
+    """Codes are the float64 nearest centroid wherever the nearest two are
+    not within rounding of each other; an encoder compiles a block shape
+    once, for any data (a flush encodes one padded block every time)."""
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((300, 4 * DSUB)).astype(np.float32)
+    cents = rng.standard_normal((4, KSUB, DSUB)).astype(np.float32)
+    codes = encode(x, cents, block=128)
+    d = np.square(x.reshape(300, 4, 1, DSUB).astype(np.float64)
+                  - cents[None]).sum(-1)                     # (300, 4, 256)
+    top2 = np.sort(d, -1)[..., :2]
+    clear = top2[..., 1] - top2[..., 0] > 1e-4
+    assert clear.mean() > 0.99
+    assert (codes[clear] == d.argmin(-1)[clear]).all()
+    enc = encoder(cents)
+    assert (np.asarray(enc(jnp.asarray(x[:128]))) == codes[:128]).all()
+    size = enc._cache_size()
+    enc(jnp.asarray(x[128:256] + 1.0))
+    assert enc._cache_size() == size == 1

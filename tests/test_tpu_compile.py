@@ -8,7 +8,9 @@ beam-search step at real widths over a small page count, all with the
 Mosaic path (`interpret=False`), for one chip of a described `v5e:2x2`.
 The beam-search test also checks that the chip's program looks up the ADC
 table without a gather of its f32 elements, while the CPU's keeps that
-gather.
+gather. Two more compile the streaming deployment's shapes (the
+`deep-freshdiskann` benchmark configuration): a flush's candidate search
+and the beam search at k + overfetch over the reserved capacity.
 
 The topology is described inside a fixture: the TPU library may be loaded
 by one process at a time, so no module of the suite touches it at import.
@@ -27,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import get_preset
 from repro.core.pages import records_per_page
 from repro.core.search_kernel import _search_batch
+from repro.core.vamana import _insert_candidates
 from repro.kernels.fused_search import fused_page_rank, page_adc
 from repro.kernels.page_scan import page_scan
 from repro.kernels.pq_adc import pq_adc
@@ -143,6 +146,62 @@ def test_search_batch_compiles_for_v5e(one_chip, preset):
     # the CPU lowering of the same step keeps its gather of table elements
     cpu_args = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
     assert "f32" in _pq_lookup_gathers(_compile(step, *cpu_args).as_text())
+
+
+# the streaming deployment: 24,576 base rows, capacity for 2^14 inserts,
+# pages of 6 deep-like records, flushes of 64 rows (MutableIndex._reserve's
+# sizing)
+STREAM_N, STREAM_CAP, STREAM_FLUSH = 24576, 24576 + 2 ** 14, 64
+STREAM_NP = PAGE_SHAPES[1][1]
+STREAM_PAGES = (-(-STREAM_N // STREAM_NP)
+                + (-(-(STREAM_CAP - STREAM_N) // STREAM_FLUSH) + 1)
+                * -(-STREAM_FLUSH // STREAM_NP))
+
+
+def test_insert_candidates_compile_for_v5e(one_chip):
+    """A flush's candidate search over the reserved capacity: d=96, R=64,
+    a batch of flush_threshold rows, insert_L=32, width 2."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d = PAGE_SHAPES[1][2]
+    compiled = _compile(
+        lambda X, G, e, q: _insert_candidates(X, G, e, q, L=32, width=2),
+        spec((STREAM_CAP, d), jnp.float32), spec((STREAM_CAP, R), jnp.int32),
+        spec((), jnp.int32), spec((STREAM_FLUSH, d), jnp.float32))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
+
+
+def test_overfetch_search_batch_compiles_for_v5e(one_chip):
+    """The merged path's one kernel shape: baseline at k + overfetch (16)
+    over the reserved capacity and page space of the streaming
+    deployment."""
+    cfg = get_preset("baseline", L=32)
+    n, P, n_p, d = STREAM_CAP, STREAM_PAGES, STREAM_NP, PAGE_SHAPES[1][2]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((P, n_p), jnp.int32), spec((P, n_p, d), jnp.float32),
+            spec((P, n_p, R), jnp.int32), spec((n,), jnp.int32),
+            spec((n,), jnp.int32), spec((M, 256, d // M), jnp.float32),
+            spec((n, M), jnp.uint8), spec((n,), jnp.bool_),
+            spec((BATCH, d), jnp.float32), spec((BATCH, 1), jnp.int32),
+            spec((BATCH, 1), jnp.bool_))
+
+    def step(*a):
+        return _search_batch(
+            *a, k=cfg.k + 16, L=cfg.L, width=cfg.beam_width,
+            max_iters=cfg.max_iters, n_p=n_p, page_search=cfg.page_search,
+            dynamic_width=cfg.dynamic_width, dw_min=cfg.dw_min,
+            dw_max=cfg.dw_max, pipeline=cfg.pipeline,
+            spec=cfg.pipeline_spec, track_visited=True)
+
+    compiled = _compile(step, *args)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 ** 30
+    assert "u8" in _pq_lookup_gathers(compiled.as_text())
 
 
 HLO_LINE = re.compile(r'^\s*(?:ROOT\s+)?%?\S+ = (\w+)\[.*?'
